@@ -42,6 +42,9 @@ def _generate(out_path: str, quick: bool) -> bool:
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [os.path.join(REPO, "src"), env.get("PYTHONPATH")] if p
     )
+    # the parent (benchmarks.run) already holds the accelerator; the dry-run
+    # compiles for placeholder CPU devices and must never reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     for shape in shapes:
         tmp = f"{out_path}.{shape}.part"
         cmd = [

@@ -46,23 +46,13 @@ __all__ = [
     "row_coded_matvec",
 ]
 
-# jax.shard_map landed in newer JAX; 0.4.x keeps it under experimental.
-# With decode_blocks now gather+matmul (no SVD custom-call), the modern
-# varying-axes checker verifies the replicated out_specs itself.  The 0.4.x
-# ``check_rep`` tracker predates that machinery and cannot infer replication
-# even through a bare all_gather, so it is disabled on that version only.
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
+# Parity blocks and recovery matrices are not bfloat16-exact, and a TPU runs
+# a float32 matmul at DEFAULT precision as one bfloat16 pass: the rounding
+# would then be amplified by the surviving blocks' condition number.  Every
+# contraction that touches coded blocks asks for full float32 precision
+# (a no-op on CPU; on TPU the head matvec is bandwidth-bound, so the extra
+# MXU passes cost nothing visible).
+EXACT = jax.lax.Precision.HIGHEST
 # --------------------------------------------------------------------------
 # Block-level systematic MDS code (identity + Cauchy parity)
 # --------------------------------------------------------------------------
@@ -154,7 +144,7 @@ def encode_blocks(w: jnp.ndarray, n_data: int, n_parity: int) -> jnp.ndarray:
     wp = jnp.pad(w, ((0, pad), (0, 0)))
     blocks = wp.reshape(n_data, br, inner)
     b = block_mds_generator(n_data + n_parity, n_data, dtype=w.dtype)
-    coded = jnp.einsum("bd,dri->bri", b, blocks)
+    coded = jnp.einsum("bd,dri->bri", b, blocks, precision=EXACT)
     return coded.reshape((n_data + n_parity) * br, inner)
 
 
@@ -181,9 +171,10 @@ def decode_blocks_svd(
         y_coded.astype(jnp.float32)
         * m.reshape((n_blocks,) + (1,) * (y_coded.ndim - 1))
     ).reshape(n_blocks, -1)
-    sol = pinv @ flat
+    sol = jnp.matmul(pinv, flat, precision=EXACT)
     for _ in range(2):  # refinement against bm (cond, not cond²)
-        sol = sol + pinv @ (flat - bm @ sol)
+        resid = flat - jnp.matmul(bm, sol, precision=EXACT)
+        sol = sol + jnp.matmul(pinv, resid, precision=EXACT)
     return sol.reshape((n_data,) + y_coded.shape[1:]).astype(y_coded.dtype)
 
 
@@ -213,7 +204,7 @@ def decode_blocks(
         y_coded.astype(jnp.float32)
         * m.reshape((n_blocks,) + (1,) * (y_coded.ndim - 1))
     ).reshape(n_blocks, -1)
-    sol = rec @ flat
+    sol = jnp.matmul(rec, flat, precision=EXACT)
     return sol.reshape((n_data,) + y_coded.shape[1:]).astype(y_coded.dtype)
 
 
@@ -296,7 +287,8 @@ class CodedLinear:
                 y = coded_matvec_decode(w_coded, x, rec, mode=kernel_mode,
                                         **params)
                 return y[: self.out_features]
-        y_coded = w_coded @ x  # rows sharded -> each device computes its block
+        # rows sharded -> each device computes its block
+        y_coded = jnp.matmul(w_coded, x, precision=EXACT)
         y_coded = y_coded.reshape(self.n_blocks, self.block_rows, -1)
         if kernel_mode == "svd":
             y = decode_blocks_svd(y_coded, mask, self.n_data, self.n_parity)
@@ -317,16 +309,21 @@ def coded_block_matmul(
     kernel_mode: str | None = None,
 ) -> jnp.ndarray:
     """shard_map form of CodedLinear.apply — the collective schedule is
-    explicit: local block matmul, all_gather of the (small) coded outputs,
-    replicated tiny decode.  Bytes on the wire: n_blocks*br*batch*4, i.e.
-    (1 + parity/data) x the uncoded all-gather — the coding overhead is
-    visible in the HLO and charged in the roofline.
+    explicit: local block matmul over this device's ``n_blocks / size``
+    contiguous code blocks, one all-reduce that assembles the (small) coded
+    outputs on every device, replicated tiny decode.  Each device writes its
+    blocks into a zero buffer at its own offset before the ``psum``, so
+    every output row has exactly one non-zero addend: the sum is exact, and
+    its result is replicated by construction, which is what the replicated
+    ``out_specs`` asks shard_map to check.  Bytes on the wire are those of
+    an all-reduce of n_blocks*br*batch*4 — the coding overhead is visible in
+    the HLO and charged in the roofline.
 
     ``kernel_mode`` routes each device's LOCAL block matmul through the
     tiled Pallas ``coded_matvec`` kernel (``'interpret'``/``'compile'``);
     None keeps the plain XLA matmul — which is also the bit-identity
     contract with the single-device CodedLinear path (same per-row dot
-    products, same decode_blocks arithmetic on the gathered outputs).
+    products, same decode_blocks arithmetic on the assembled outputs).
     ``'auto'`` resolves per LOCAL shard shape at trace time
     (``repro.kernels.dispatch``); when the dispatcher picks the jnp
     reference it degrades to the plain matmul, preserving the bit-identity
@@ -350,12 +347,16 @@ def coded_block_matmul(
 
             y_local = coded_matvec(wc, xc, mode=mode, **params)
         else:
-            y_local = wc @ xc                   # [br_local, batch]
-        y_all = jax.lax.all_gather(y_local, axis, axis=0, tiled=True)
-        y_all = y_all.reshape(n_blocks, br, -1)
+            y_local = jnp.matmul(wc, xc, precision=EXACT)  # [rows_local, batch]
+        rows = y_local.shape[0]
+        y_all = jnp.zeros((n_blocks * br,) + y_local.shape[1:], y_local.dtype)
+        y_all = jax.lax.dynamic_update_slice_in_dim(
+            y_all, y_local, jax.lax.axis_index(axis) * rows, axis=0
+        )
+        y_all = jax.lax.psum(y_all, axis).reshape(n_blocks, br, -1)
         return decode_blocks(y_all, m, n_data, n_parity).reshape(n_data * br, -1)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None)),

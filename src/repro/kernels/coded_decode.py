@@ -8,20 +8,20 @@ back, and n_data*br*B written again.  This kernel applies the recovery
 matrix while the block outputs are still VMEM-resident (DESIGN.md §6):
 
   * grid (br/BT, M/BM): row tiles x column panels, column panel innermost so
-    the fp32 *decoded* accumulator stays resident and accumulates across
-    panels — ONE HBM write per row tile, and the coded partials never leave
-    VMEM;
-  * decode distributes over the contraction: R (y_c^j summed over panels j)
-    == sum_j R y_c^j, so each panel's [n_blocks, BT, B] partial is contracted
-    with R ([n_data, n_blocks]) immediately — one extra [n_data, n_blocks] x
-    [n_blocks, BT*B] matmul per grid step, negligible next to the block GEMM;
+    the fp32 coded partials [n_blocks*BT, B] accumulate across panels in a
+    VMEM scratch — the coded partials never leave VMEM;
+  * on the last panel the row tile is decoded in place: each of the n_data
+    output blocks is sum_k R[d, k] y_c[k] over the [BT, B] block partials,
+    with R ([n_data, n_blocks]) read as scalars from SMEM — n_data*n_blocks
+    vector FMAs per row tile, negligible next to the block GEMM, and ONE HBM
+    write per row tile;
   * the recovery matrix is the mask-keyed cached pseudo-inverse
     (``repro.core.decoding.DecoderCache``) — erased blocks' columns are
     exactly zero, so their (finite) garbage cannot reach the output;
   * VMEM budget at the default (BT, BM) = (128, 512) with the 16-block
-    serving head: W tile 16*128*512*4 = 4 MB + x 16 KB + R 1 KB + out
-    (16 blocks -> n_data<=16) <= 64 KB  ~=  4.1 MB  <  16 MB, double-buffered
-    comfortably at 8 MB.  Shrink ``block_t`` for wider codes.
+    serving head: W tile 16*128*512*4 = 4 MB + x 16 KB + scratch and out
+    (16 blocks, B <= 8 lanes padded to 128) 2 x 1 MB ~= 6 MB < 16 MB; the
+    W tile is double-buffered.  Shrink ``block_t`` for wider codes.
 
 The jnp oracle is ``repro.kernels.ref.ref_coded_matvec_decode``; the public
 wrapper (mode-switchable) is ``repro.kernels.ops.coded_matvec_decode``.
@@ -33,30 +33,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["coded_matvec_decode_pallas"]
 
+# parity blocks and recovery weights are not bfloat16-exact: the block GEMM
+# runs at full float32 precision (see repro.core.coded_ops.EXACT)
+_EXACT = jax.lax.Precision.HIGHEST
 
-def _kernel(r_ref, a_ref, x_ref, o_ref):
+
+def _kernel(r_ref, a_ref, x_ref, o_ref, acc_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[...]                     # [n_blocks, BT, BM]
     nb, bt, bm = a.shape
-    # block GEMM on the MXU: [n_blocks*BT, BM] x [BM, B]
-    yc = jnp.dot(
+    # block GEMM on the MXU: [n_blocks*BT, BM] x [BM, B], accumulated over
+    # the column panels in VMEM scratch
+    acc_ref[...] += jnp.dot(
         a.reshape(nb * bt, bm).astype(jnp.float32),
         x_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
-    ).reshape(nb, bt, -1)
-    # fused decode while VMEM-resident: [n_data, nb] x [nb, BT*B]
-    r = r_ref[...].astype(jnp.float32)  # [n_data, n_blocks]
-    o_ref[...] += jnp.dot(
-        r, yc.reshape(nb, -1), preferred_element_type=jnp.float32
-    ).reshape(r.shape[0], bt, -1)
+        precision=_EXACT,
+    )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _decode():
+        # decode while VMEM-resident, once per row tile: each data block is
+        # a combination of the [BT, B] block partials with SMEM scalar
+        # weights (a [n_blocks, BT*B] view of the partials would fold the
+        # batch into the lane dim, which the TPU's layouts cannot do)
+        yc = acc_ref[...].reshape(nb, bt, -1)
+        for d in range(o_ref.shape[0]):
+            y = r_ref[d, 0] * yc[0]
+            for k in range(1, nb):
+                y = y + r_ref[d, k] * yc[k]
+            o_ref[d] = y
 
 
 @functools.partial(
@@ -70,7 +85,7 @@ def coded_matvec_decode_pallas(
     n_blocks: int | None = None,
     block_t: int = 128,
     block_m: int = 512,
-    interpret: bool = True,   # CPU container: interpret; TPU: False
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """y = R·(blocked W_c x), decoded in-kernel — returns [n_data * br(, B)]."""
     squeeze = x.ndim == 1
@@ -92,13 +107,14 @@ def coded_matvec_decode_pallas(
         _kernel,
         grid=(tp // bt, mp // bm),
         in_specs=[
-            pl.BlockSpec((n_data, nb), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((nb, bt, bm), lambda i, j: (0, i, j)),
             pl.BlockSpec((bm, b), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((n_data, bt, b), lambda i, j: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_data, tp, b), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((nb * bt, b), jnp.float32)],
         interpret=interpret,
-    )(rec, a_p, x_p)
+    )(rec.astype(jnp.float32), a_p, x_p)
     out = out[:, :br].reshape(n_data * br, b)
     return out[:, 0] if squeeze else out

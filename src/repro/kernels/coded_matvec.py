@@ -40,6 +40,7 @@ def _kernel(a_ref, x_ref, o_ref):
         a_ref[...].astype(jnp.float32),
         x_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # coded rows are not bf16-exact
     )
 
 
@@ -50,7 +51,7 @@ def coded_matvec_pallas(
     *,
     block_r: int = 256,
     block_m: int = 512,
-    interpret: bool = True,   # CPU container: interpret; TPU: False
+    interpret: bool = False,
 ) -> jnp.ndarray:
     squeeze = x.ndim == 1
     if squeeze:

@@ -127,12 +127,24 @@ TPU_V5E_HOST = HostHardware(
 )
 
 _PRESETS = {"cpu": CPU_HOST, "tpu": TPU_V5E_HOST}
+# the device kinds (jax ``Device.device_kind``) each accelerator preset
+# describes: another chip's peaks are no prior for a chip
+_PRESET_KINDS = {"tpu": ("TPU v5 lite",)}
 
 
-def preset(backend: str) -> HostHardware:
-    """Hardware prior for a jax backend name (unknown accelerators get the
-    TPU-shaped overlap model — they share the 'no in-graph SVD' property)."""
-    return _PRESETS.get(backend, TPU_V5E_HOST)
+def preset(backend: str, device_kind: str | None = None) -> HostHardware:
+    """Hardware prior for a jax backend name; ``device_kind``, where given,
+    must be one the preset describes.  A backend or device kind with no
+    preset is an error, never another chip's numbers."""
+    if backend not in _PRESETS:
+        raise ValueError(f"no hardware preset for backend {backend!r}")
+    kinds = _PRESET_KINDS.get(backend)
+    if device_kind is not None and kinds is not None and device_kind not in kinds:
+        raise ValueError(
+            f"no hardware preset for {backend} device kind {device_kind!r} "
+            f"(known: {', '.join(kinds)})"
+        )
+    return _PRESETS[backend]
 
 
 @dataclass(frozen=True)

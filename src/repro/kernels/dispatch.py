@@ -121,7 +121,7 @@ class DispatchTable:
         return cls(doc)
 
     def hardware(self, backend: str) -> _cost.HostHardware:
-        return self._hw.get(backend, _cost.preset(backend))
+        return self._hw.get(backend) or _cost.preset(backend)
 
     def lookup(self, op: str, backend: str, shape: str,
                dtype: str = "float32", geometry: dict | None = None
@@ -169,9 +169,13 @@ def get_table() -> DispatchTable | None:
 
 
 def _backend() -> str:
+    """The default backend, checked against the hardware presets: on a
+    device kind the cost model has no preset for, dispatch is an error."""
     import jax
 
-    return jax.default_backend()
+    backend = jax.default_backend()
+    _cost.preset(backend, jax.devices()[0].device_kind)
+    return backend
 
 
 def _resolve(op: str, shape: str, geometry: dict | None,

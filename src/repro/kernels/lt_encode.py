@@ -5,11 +5,13 @@ row-gather + accumulate.  On TPU, arbitrary dynamic gathers inside a kernel
 are expressed with **scalar prefetch**: the degree table (indices, coeffs)
 is prefetched to SMEM and the A BlockSpec's index_map reads the *source row
 id* from it — the DMA engine then streams exactly the needed [1, BM] row
-panel HBM->VMEM per grid step:
+panel HBM->VMEM per grid step.  A and the output are viewed as [rows, 1, M]
+so a one-row block spans a whole (second-minor) dim, as the TPU's (8, 128)
+block tiling requires:
 
     grid = (q, M/BM, d_max)   (d innermost: output panel accumulates in VMEM)
-    A block     (1, BM)  at (indices[i, d], j)
-    out block   (1, BM)  at (i, j)
+    A block     (1, BM)  at (indices[i, d], 0, j)
+    out block   (1, BM)  at (i, 0, j)
 
 Padding entries (coeff 0) gather row 0 and multiply by zero.  Row blocks of
 height 1 trade MXU alignment for gather flexibility — acceptable because
@@ -64,27 +66,35 @@ def lt_encode_pallas(
     coeffs: jnp.ndarray,    # [q, d_max] float32 (0 = padding)
     *,
     block_m: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     r, m = a.shape
     q, d_max = indices.shape
     bm = min(block_m, m)
     mp = -(-m // bm) * bm
-    a_p = jnp.pad(a, ((0, 0), (0, mp - m)))
+    # rows as a leading axis of [r, 1, M]: a (1, BM) block then spans the
+    # whole second-minor dim, which the TPU's (8, 128) tiling accepts
+    a_p = jnp.pad(a, ((0, 0), (0, mp - m))).reshape(r, 1, mp)
     out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(q, mp // bm, d_max),
             in_specs=[
-                pl.BlockSpec((1, bm), lambda i, j, d, idx_ref, cf_ref: (idx_ref[i, d], j)),
+                pl.BlockSpec(
+                    (pl.Squeezed(), 1, bm),
+                    lambda i, j, d, idx_ref, cf_ref: (idx_ref[i, d], 0, j),
+                ),
             ],
-            out_specs=pl.BlockSpec((1, bm), lambda i, j, d, idx_ref, cf_ref: (i, j)),
+            out_specs=pl.BlockSpec(
+                (pl.Squeezed(), 1, bm),
+                lambda i, j, d, idx_ref, cf_ref: (i, 0, j),
+            ),
         ),
-        out_shape=jax.ShapeDtypeStruct((q, mp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((q, 1, mp), jnp.float32),
         interpret=interpret,
     )(indices.astype(jnp.int32), coeffs.astype(jnp.float32), a_p)
-    return out[:, :m]
+    return out[:, 0, :m]
 
 
 def _gauss_kernel(g_ref, a_ref, o_ref):
@@ -98,6 +108,7 @@ def _gauss_kernel(g_ref, a_ref, o_ref):
         g_ref[...].astype(jnp.float32),
         a_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # generators are not bf16-exact
     )
 
 
@@ -111,7 +122,7 @@ def gaussian_encode_pallas(
     block_q: int = 128,
     block_m: int = 512,
     block_r: int = 512,
-    interpret: bool = True,   # CPU container: interpret; TPU: False
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Â = G A, tiled for the MXU — the on-device dense/reserve encode."""
     q, r = g.shape

@@ -1,9 +1,11 @@
 """jit'd public wrappers over the Pallas kernels (with jnp fallback).
 
-``use_pallas='interpret'`` (default here) runs the kernel bodies through
-the Pallas interpreter — bit-faithful to the TPU kernel dataflow, executable
-on CPU.  On real TPU pass ``use_pallas='compile'``.  ``'off'`` routes to the
-pure-jnp reference (the oracle itself), useful for A/B in benchmarks.
+``mode`` picks the implementation.  Left as None it follows the platform
+(``platform_mode``): the compiled kernel on a TPU, the pure-jnp reference
+(``'off'``, the oracle itself) anywhere else.  ``'interpret'`` runs the
+kernel bodies through the Pallas interpreter — the kernel dataflow,
+executable on CPU — and is only ever asked for by name, by the tests that
+check a kernel against its oracle.
 
 ``'auto'`` consults the dispatch table / analytical cost model
 (``repro.kernels.dispatch``, DESIGN.md §11): the implementation AND its
@@ -28,11 +30,18 @@ from repro.kernels.ssd_scan import ssd_chunk_pallas, ssd_combine_pallas
 Mode = Literal["interpret", "compile", "off", "auto"]
 
 
+def platform_mode() -> str:
+    """The kernel mode of the default backend: the Pallas TPU kernels
+    compile only for a TPU; elsewhere the jnp reference runs."""
+    return "compile" if jax.default_backend() == "tpu" else "off"
+
+
 def _auto(decision, kw: dict) -> tuple[str, dict]:
     """(mode, kwargs) from a dispatch Decision; caller kwargs win."""
     return decision.mode or "off", {**decision.params, **kw}
 
 __all__ = [
+    "platform_mode",
     "coded_matvec",
     "coded_matvec_decode",
     "coded_head_matvec",
@@ -44,7 +53,8 @@ __all__ = [
 ]
 
 
-def coded_matvec(a, x, mode: Mode = "interpret", **kw):
+def coded_matvec(a, x, mode: Mode | None = None, **kw):
+    mode = mode or platform_mode()
     if mode == "auto":
         from repro.kernels.dispatch import choose_matvec
         from repro.sharding.ctx import current_macro_step_k
@@ -60,12 +70,13 @@ def coded_matvec(a, x, mode: Mode = "interpret", **kw):
     return coded_matvec_pallas(a, x, interpret=(mode == "interpret"), **kw)
 
 
-def coded_matvec_decode(a, x, rec, mode: Mode = "interpret", **kw):
+def coded_matvec_decode(a, x, rec, mode: Mode | None = None, **kw):
     """Fused coded block matmul + erasure decode (DESIGN.md §6).
 
     ``rec`` is the mask-keyed [n_data, n_blocks] recovery matrix from
     ``repro.core.decoding.DecoderCache.recovery(mask)``.
     """
+    mode = mode or platform_mode()
     if mode == "auto":
         from repro.kernels.dispatch import choose_matvec_decode
         from repro.sharding.ctx import current_macro_step_k
@@ -97,11 +108,12 @@ def coded_head_matvec(
     (DESIGN.md §10).  w_coded [(n_data+n_parity)*br, in], x [in, batch],
     mask [n_blocks] -> y [n_data*br, batch] fp32.
 
-      * ``mesh`` given — shard_map over ``axis``: one code block per
-        device, local block matmul (optionally the Pallas ``coded_matvec``
-        kernel via ``kernel_mode``), all_gather of the small coded outputs,
-        replicated mask-keyed DecoderCache decode.  Erasing a device's
-        output is exactly zeroing its block in the mask.
+      * ``mesh`` given — shard_map over ``axis``: whole code blocks per
+        device (one each when the axis is as long as the code), local
+        block matmul (optionally the Pallas ``coded_matvec`` kernel via
+        ``kernel_mode``), a psum that assembles the small coded outputs,
+        replicated mask-keyed DecoderCache decode.  Erasing a block is
+        exactly zeroing it in the mask.
       * no mesh — the single-program CodedLinear path: one fused block
         matmul + cached decode (or the fused Pallas matmul+decode kernel
         when ``kernel_mode`` is set).
@@ -124,7 +136,8 @@ def coded_head_matvec(
     return cl.apply(w_coded, x, mask, kernel_mode=kernel_mode)
 
 
-def lt_encode(a, indices, coeffs, mode: Mode = "interpret", **kw):
+def lt_encode(a, indices, coeffs, mode: Mode | None = None, **kw):
+    mode = mode or platform_mode()
     if mode == "auto":
         from repro.kernels.dispatch import choose_encode
 
@@ -138,8 +151,9 @@ def lt_encode(a, indices, coeffs, mode: Mode = "interpret", **kw):
     return lt_encode_pallas(a, indices, coeffs, interpret=(mode == "interpret"), **kw)
 
 
-def gaussian_encode(g, a, mode: Mode = "interpret", **kw):
+def gaussian_encode(g, a, mode: Mode | None = None, **kw):
     """Â = G A for a dense generator slice (tiled MXU matmul, DESIGN.md §9)."""
+    mode = mode or platform_mode()
     if mode == "auto":
         from repro.kernels.dispatch import choose_encode
 
@@ -151,7 +165,8 @@ def gaussian_encode(g, a, mode: Mode = "interpret", **kw):
     return gaussian_encode_pallas(g, a, interpret=(mode == "interpret"), **kw)
 
 
-def encode_rows(a, plan, start: int, stop: int, mode: Mode = "interpret", **kw):
+def encode_rows(a, plan, start: int, stop: int, mode: Mode | None = None,
+                **kw):
     """On-device encode of plan rows [start, stop) — the reserve top-up path.
 
     Dispatches by code family: dense (gaussian) plans go through the tiled
@@ -176,7 +191,7 @@ def encode_rows(a, plan, start: int, stop: int, mode: Mode = "interpret", **kw):
 
 
 def encode_blocks_device(
-    w, n_data: int, n_parity: int, mode: Mode = "interpret", **kw
+    w, n_data: int, n_parity: int, mode: Mode | None = None, **kw
 ):
     """Block-MDS weight encode through the tiled kernel (DESIGN.md §9).
 
@@ -203,7 +218,7 @@ def ssd_forward(
     b: jnp.ndarray,    # [B, S, G, N]
     c: jnp.ndarray,    # [B, S, G, N]
     chunk: int,
-    mode: Mode = "interpret",
+    mode: Mode | None = None,
     h0: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Full SSD using the Pallas chunk kernels + jnp inter-chunk scan.
@@ -211,6 +226,7 @@ def ssd_forward(
     Drop-in equivalent of ``repro.models.ssm.ssd_chunked`` (the oracle).
     Returns (y [B,S,H,P], final_state [B,H,P,N]).
     """
+    mode = mode or platform_mode()
     bsz, s, h, p = x.shape
     g_, n = b.shape[2], b.shape[3]
     q = min(chunk, s)

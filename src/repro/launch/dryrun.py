@@ -12,13 +12,17 @@ compiled artifact yields the memory/cost/collective numbers the roofline
 analysis (EXPERIMENTS.md §Roofline) reads.
 """
 # The dry-run (and ONLY the dry-run) needs 512 placeholder devices; jax
-# locks the device count on first init, so this MUST precede every import.
-# Inherited force flags are stripped first: XLA keeps the LAST duplicate
-# flag, and callers (e.g. a pytest parent whose conftest forces 16 devices
-# for the shard_map serving tests) would otherwise silently override the
-# 512 this launcher requires.
+# locks the device count and the platform on first init, so this MUST
+# precede every import.  The platform is pinned to the CPU: these are
+# placeholder devices by design, and on a machine with an accelerator the
+# process that launched the dry-run may already hold it.  Inherited force
+# flags are stripped first: XLA keeps the LAST duplicate flag, and callers
+# (e.g. a pytest parent whose conftest forces 16 devices for the shard_map
+# serving tests) would otherwise silently override the 512 this launcher
+# requires.
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + " ".join(

@@ -40,7 +40,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
 from repro.cluster.straggler import MarkovStragglerPolicy
 from repro.configs import get_config
@@ -71,7 +71,10 @@ def make_local_mesh(model: int | None = None):
     elif n % model != 0:
         raise ValueError(f"--mesh-model {model} does not divide {n} devices")
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    # Auto axes: the train step places state by NamedSharding and lets GSPMD
+    # propagate, which Explicit axes (jax.make_mesh's default) refuse
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _allowed_levels(kind: str, m: int, s_max: int) -> list[int]:

@@ -143,9 +143,12 @@ def init_lm(key, cfg: ModelConfig) -> Params:
 
         head = params["lm_head"] if "lm_head" in params else params["embed"].T
         n_blocks = _coded_blocks(cfg)
+        # float32 whatever the param dtype: parity blocks are combinations
+        # of head rows that a narrower dtype would round, and erasure decode
+        # amplifies that rounding by the surviving blocks' condition number
         params["lm_head_coded"] = encode_blocks(
             head.T.astype(jnp.float32), n_blocks - cfg.coded_parity, cfg.coded_parity
-        ).astype(pdt)
+        )
     return params
 
 

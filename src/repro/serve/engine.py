@@ -114,7 +114,7 @@ class ServeEngine:
         parity_controller: "ParityController | None" = None,
         parity_topup: int = 0,
         topup_patience: int = 4,
-        encode_mode: str = "interpret",
+        encode_mode: str | None = None,
         mesh=None,
         head_axis: str = "model",
         head_kernel_mode: str | None = None,
@@ -131,13 +131,16 @@ class ServeEngine:
         re-encoded with one more parity block ON DEVICE through the tiled
         Pallas encode kernel (``kernels.ops.encode_blocks_device``,
         DESIGN.md §9) — the serving analogue of the executor's reserve
-        top-up.  ``encode_mode`` is the kernel mode for those re-encodes.
+        top-up.  ``encode_mode`` is the kernel mode for those re-encodes
+        (None follows the platform: the compiled kernel on a TPU, the jnp
+        reference elsewhere).
 
         ``mesh`` shards the coded head over a real ``jax.sharding.Mesh``:
-        one code block per device along ``head_axis``, erasure = dropping a
-        device's output, decode via the mask-keyed DecoderCache — the
-        single-device path is bit-identical on identical masks (DESIGN.md
-        §10).  ``scheduler`` switches admission to a trace-driven
+        whole code blocks per device along ``head_axis`` (whose size must
+        divide the block count; one block per device makes erasure =
+        dropping a device's output), decode via the mask-keyed
+        DecoderCache — the single-device path is bit-identical on
+        identical masks (DESIGN.md §10).  ``scheduler`` switches admission to a trace-driven
         ``serve.scheduler.TraceScheduler`` (open-loop arrivals, deadlines,
         admission control); its request payloads must be ``Request``
         objects.  ``parity_policy`` replaces the raw ParityController level
@@ -227,12 +230,24 @@ class ServeEngine:
             )
 
             validate_coded_head_mesh(mesh, self._n_blocks, head_axis)
-            # place the coded head once with its block sharding so the
-            # per-step shard_map never reshards the weight
-            self.params = dict(self.params)
-            self.params["lm_head_coded"] = jax.device_put(
-                self.params["lm_head_coded"], coded_head_sharding(mesh, head_axis)
+            # place every array once: the coded head with its block sharding
+            # (so the per-step shard_map never reshards the weight), the
+            # rest of the model, the cache and the token carry replicated
+            # on the mesh (left on the default device they would be copied
+            # to every other device at each step)
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            rep = NamedSharding(mesh, PartitionSpec())
+            head = self.params["lm_head_coded"]
+            self.params = jax.device_put(
+                {k: v for k, v in self.params.items() if k != "lm_head_coded"},
+                rep,
             )
+            self.params["lm_head_coded"] = jax.device_put(
+                head, coded_head_sharding(mesh, head_axis)
+            )
+            self.cache = jax.device_put(self.cache, rep)
+            self._last_tok = jax.device_put(self._last_tok, rep)
         self._bind_model(model)
         self.completed: list[Request] = []
 
@@ -443,7 +458,7 @@ class ServeEngine:
         round-trip.  The decode/prefill steps re-jit once per raise."""
         import dataclasses
 
-        from repro.kernels.ops import encode_blocks_device
+        from repro.kernels.ops import encode_blocks_device, platform_mode
         from repro.models.registry import build_model
 
         cfg = self.model.cfg
@@ -454,11 +469,12 @@ class ServeEngine:
             else self.params["embed"].T
         )
         pdt = self.params["lm_head_coded"].dtype
+        mode = self.encode_mode or platform_mode()
         coded = encode_blocks_device(
             head.T.astype(jnp.float32),
             self._n_blocks - new_parity,
             new_parity,
-            mode=self.encode_mode,
+            mode=mode,
         )
         # shallow-copy so the caller's params dict (possibly shared with
         # other engines) keeps its original-geometry coded head
@@ -477,7 +493,7 @@ class ServeEngine:
         self.parity_events.append({
             "step": self._steps,
             "n_parity": new_parity,
-            "encode_mode": self.encode_mode,
+            "encode_mode": mode,
         })
 
     # ------------------------------------------------------------------

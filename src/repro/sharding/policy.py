@@ -44,7 +44,6 @@ __all__ = [
     "ShardingPolicy",
     "make_policy",
     "param_specs",
-    "serve_head_mesh",
     "coded_head_sharding",
     "validate_coded_head_mesh",
 ]
@@ -286,33 +285,20 @@ def param_specs(shapes: Any, mesh: Mesh, **kw) -> Any:
 
 
 # --------------------------------------------------------------------------
-# Coded serving head: one code block per device (DESIGN.md §10)
+# Coded serving head: whole code blocks per device (DESIGN.md §10)
 # --------------------------------------------------------------------------
-def serve_head_mesh(n_blocks: int, axis: str = "model") -> Mesh:
-    """A 1-D serving mesh with one device per coded head block.
-
-    The coded LM head's erasure unit is the BLOCK; putting exactly one
-    block on each device makes "a device straggled/died" and "a block is
-    erased" the same event — the geometry the shard_map head assumes."""
-    devs = jax.devices()
-    if len(devs) < n_blocks:
-        raise ValueError(
-            f"serve_head_mesh needs {n_blocks} devices (one per code "
-            f"block), have {len(devs)}"
-        )
-    return Mesh(np.array(devs[:n_blocks]), (axis,))
-
-
 def validate_coded_head_mesh(mesh: Mesh, n_blocks: int, axis: str = "model") -> None:
-    """Assert the one-block-per-device geometry the shard_map head needs."""
+    """Assert the geometry the shard_map head needs: whole code blocks per
+    device, i.e. an axis size that divides the block count.  Erasure stays
+    per block; with one block per device it is also per device."""
     if axis not in mesh.axis_names:
         raise ValueError(f"mesh has no {axis!r} axis (axes: {mesh.axis_names})")
     size = mesh.shape[axis]
-    if size != n_blocks:
+    if n_blocks % size:
         raise ValueError(
             f"coded head has {n_blocks} blocks but mesh axis {axis!r} has "
-            f"{size} devices; the sharded head wants exactly one block per "
-            f"device (erasure = dropping a device's output)"
+            f"{size} devices; the sharded head wants whole blocks on every "
+            f"device, so the axis size must divide the block count"
         )
 
 

@@ -129,5 +129,9 @@ def test_bpcc_allocation_properties(n, seed, p):
     # faster workers (smaller alpha+1/mu) get >= loads of slower ones, on
     # average: check rank correlation is non-positive
     cost = np.array([w.alpha + 1 / w.mu for w in ws])
+    if np.ptp(alloc.loads) == 0:
+        # near-identical workers (e.g. n=2, seed=3073) get equal loads: the
+        # correlation is undefined and no worker is favoured
+        return
     rho = np.corrcoef(cost, alloc.loads)[0, 1]
     assert rho < 0.5  # weakly anti-correlated (noise tolerated)

@@ -249,3 +249,18 @@ def test_head_kernel_mode_ctxvar():
         with head_kernel_mode(None):  # None = no-op passthrough
             assert current_head_kernel_mode() == "auto"
     assert current_head_kernel_mode() is None
+
+
+def test_unknown_hardware_is_an_error():
+    """Another chip's peaks are no prior: a backend or TPU device kind with
+    no preset raises instead of borrowing the v5e numbers."""
+    assert cost.preset("tpu", "TPU v5 lite") is cost.preset("tpu")
+    assert cost.preset("cpu", "cpu") is cost.preset("cpu")
+    with pytest.raises(ValueError, match="device kind"):
+        cost.preset("tpu", "TPU v4")
+    with pytest.raises(ValueError, match="backend"):
+        cost.preset("gpu")
+    # the dispatcher checks the process's own device on every resolution
+    d = choose_matvec(512, 512, 4)
+    assert d.mode in ("off", None)
+

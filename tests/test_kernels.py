@@ -25,7 +25,8 @@ def test_coded_matvec_sweep(r, m, b, dtype):
     rng = np.random.default_rng(r * 1000 + m)
     a = rng.standard_normal((r, m)).astype(dtype)
     x = (rng.standard_normal((m, b)) if b > 1 else rng.standard_normal(m)).astype(dtype)
-    got = np.asarray(coded_matvec(jnp.asarray(a), jnp.asarray(x)))
+    got = np.asarray(coded_matvec(jnp.asarray(a), jnp.asarray(x),
+                                  mode="interpret"))
     want = np.asarray(R.ref_coded_matvec(jnp.asarray(a), jnp.asarray(x)))
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3 * max(1, np.abs(want).max()))
 
@@ -38,7 +39,7 @@ def test_coded_matvec_property(r, m, b, br, bm):
     a = rng.standard_normal((r, m)).astype(np.float32)
     x = rng.standard_normal((m, b)).astype(np.float32)
     got = np.asarray(coded_matvec(jnp.asarray(a), jnp.asarray(x),
-                                  block_r=br, block_m=bm))
+                                  mode="interpret", block_r=br, block_m=bm))
     np.testing.assert_allclose(got, a @ x, rtol=1e-4, atol=1e-4 * max(1, np.abs(a @ x).max()))
 
 
@@ -98,7 +99,7 @@ def test_lt_encode_sweep(r, q, m, code):
     a = rng.standard_normal((r, m)).astype(np.float32)
     plan = (LTCode(r=r, seed=1) if code == "lt" else GaussianCode(r=r, seed=1)).plan(q)
     got = np.asarray(lt_encode(jnp.asarray(a), jnp.asarray(plan.indices),
-                               jnp.asarray(plan.coeffs)))
+                               jnp.asarray(plan.coeffs), mode="interpret"))
     want = np.asarray(R.ref_lt_encode(jnp.asarray(a), jnp.asarray(plan.indices),
                                       jnp.asarray(plan.coeffs)))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
@@ -117,7 +118,7 @@ def test_ssd_forward_matches_model_oracle(B, S, H, P, G, N, Q):
     da = jnp.asarray(-np.abs(rng.standard_normal((B, S, H))) * 0.3, jnp.float32)
     b_ = jnp.asarray(rng.standard_normal((B, S, G, N)) * 0.3, jnp.float32)
     c_ = jnp.asarray(rng.standard_normal((B, S, G, N)) * 0.3, jnp.float32)
-    y_k, f_k = ssd_forward(x, da, b_, c_, chunk=Q)
+    y_k, f_k = ssd_forward(x, da, b_, c_, chunk=Q, mode="interpret")
     y_o, f_o = ssd_chunked(x, da, b_, c_, chunk=Q)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_o), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(f_k), np.asarray(f_o), rtol=1e-4, atol=1e-5)
@@ -131,7 +132,7 @@ def test_ssd_forward_with_initial_state():
     b_ = jnp.asarray(rng.standard_normal((B, S, G, N)) * 0.3, jnp.float32)
     c_ = jnp.asarray(rng.standard_normal((B, S, G, N)) * 0.3, jnp.float32)
     h0 = jnp.asarray(rng.standard_normal((B, H, P, N)) * 0.1, jnp.float32)
-    y_k, f_k = ssd_forward(x, da, b_, c_, chunk=8, h0=h0)
+    y_k, f_k = ssd_forward(x, da, b_, c_, chunk=8, mode="interpret", h0=h0)
     y_o, f_o = ssd_chunked(x, da, b_, c_, chunk=8, h0=h0)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_o), rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(f_k), np.asarray(f_o), rtol=1e-4, atol=1e-5)
@@ -209,3 +210,23 @@ def test_kernel_off_mode_is_reference():
     x = rng.standard_normal(48).astype(np.float32)
     got = np.asarray(coded_matvec(jnp.asarray(a), jnp.asarray(x), mode="off"))
     np.testing.assert_allclose(got, a @ x, rtol=1e-5, atol=1e-5)
+
+
+def test_default_mode_follows_the_platform():
+    """With no mode the wrappers take the platform's path: the jnp
+    reference off a TPU (never the interpreter), bit for bit."""
+    from repro.kernels.ops import platform_mode
+
+    assert platform_mode() == "off"  # the tests run on the CPU
+    rng = np.random.default_rng(12)
+    a = jnp.asarray(rng.standard_normal((40, 24)).astype(np.float32))
+    x = jnp.asarray(rng.standard_normal((24, 3)).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(coded_matvec(a, x)), np.asarray(coded_matvec(a, x, mode="off"))
+    )
+    g = jnp.asarray(rng.standard_normal((5, 40)).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(gaussian_encode(g, a)),
+        np.asarray(gaussian_encode(g, a, mode="off")),
+    )
+

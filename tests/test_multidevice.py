@@ -24,8 +24,9 @@ def run_py(code: str, n_devices: int = 8, timeout: int = 420) -> str:
 def test_shard_map_coded_block_matmul():
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.core.coded_ops import coded_block_matmul, CodedLinear
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
         cl = CodedLinear(n_data=6, n_parity=2, out_features=48)
         rng = np.random.default_rng(0)
         w = rng.standard_normal((48, 32)).astype(np.float32)
@@ -44,7 +45,7 @@ def test_shard_map_coded_block_matmul():
 def test_pjit_train_step_on_mesh():
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding
+        from jax.sharding import AxisType, NamedSharding
         from repro.configs import get_config
         from repro.models.registry import build_model
         from repro.optim import AdamWConfig
@@ -55,7 +56,8 @@ def test_pjit_train_step_on_mesh():
 
         cfg = get_config("glm4-9b", smoke=True)
         model = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         policy = make_policy(mesh, cfg)
         opt = AdamWConfig(lr=1e-3, moment_dtype="int8")
         state_sds = jax.eval_shape(lambda k: init_train_state(model, k, opt),
@@ -83,7 +85,7 @@ def test_sharded_equals_single_device():
     """The pjit'd step on a 2x2 mesh reproduces the single-device update."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding
+        from jax.sharding import AxisType, NamedSharding
         from repro.configs import get_config
         from repro.models.registry import build_model
         from repro.optim import AdamWConfig
@@ -104,7 +106,8 @@ def test_sharded_equals_single_device():
         s1, _ = jax.jit(step_fn)(s0, batch)
 
         # 2x2 mesh
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         policy = make_policy(mesh, cfg)
         sds = jax.eval_shape(lambda k: init_train_state(model, k, opt),
                              jax.random.key(0))
@@ -183,3 +186,43 @@ def test_dryrun_cell_subprocess():
     )
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     assert out.stdout.count("OK") == 2  # single-pod AND multi-pod
+
+
+def test_dryrun_pins_the_cpu_platform():
+    """The dry-run compiles for placeholder CPU devices: importing it pins
+    JAX to the CPU whatever the caller's environment says, so it never
+    reaches for an accelerator its parent may hold."""
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent("""
+            import os
+            import repro.launch.dryrun
+            import jax
+            print(os.environ["JAX_PLATFORMS"], jax.default_backend(),
+                  len(jax.devices()))
+        """)],
+        capture_output=True, text=True, timeout=300, env=env, cwd=REPO,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["cpu", "cpu", "512"]
+
+
+def test_compile_cache_location():
+    """$JAX_COMPILATION_CACHE_DIR when set, else .jax_cache/ in the checkout
+    — a fixed path, so one run's compiles serve the next."""
+    code = "import repro, jax; print(jax.config.jax_compilation_cache_dir)"
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    got = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env, cwd="/")
+    assert got.returncode == 0, got.stderr[-2000:]
+    assert got.stdout.strip() == os.path.join(REPO, ".jax_cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(REPO, ".jax_cache_env")
+    got = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env, cwd="/")
+    assert got.returncode == 0, got.stderr[-2000:]
+    assert got.stdout.strip() == env["JAX_COMPILATION_CACHE_DIR"]
+

@@ -22,10 +22,13 @@ def coded_model():
     return cfg, model, params
 
 
-def _mesh():
-    from repro.sharding.policy import serve_head_mesh
+def _mesh(size: int = N_BLOCKS):
+    """A 1-D ``model`` mesh of ``size`` devices: one code block per device
+    at the default, several (N_BLOCKS / size) at a divisor of the count."""
+    from jax.sharding import Mesh
 
-    return serve_head_mesh(N_BLOCKS)
+    require_devices(size)
+    return Mesh(np.array(jax.devices()[:size]), ("model",))
 
 
 # --------------------------------------------------------------------------
@@ -64,16 +67,46 @@ def test_coded_head_matvec_sharded_matches_single_device():
         assert np.abs(got - exact).max() / np.abs(exact).max() < 1e-3
 
 
+@pytest.mark.parametrize("size", [2, 4, 8])
+def test_coded_head_matvec_several_blocks_per_device(size):
+    """A mesh axis that divides the block count holds N_BLOCKS / size whole
+    blocks per device; erasure stays per block, and the result matches the
+    single-program head on every mask."""
+    from repro.core.coded_ops import CodedLinear
+    from repro.kernels.ops import coded_head_matvec
+
+    n_data, n_parity = N_BLOCKS - 2, 2
+    rng = np.random.default_rng(size)
+    w = rng.standard_normal((220, 32)).astype(np.float32)
+    cl = CodedLinear(n_data=n_data, n_parity=n_parity, out_features=220)
+    wc = cl.encode(jnp.asarray(w))
+    x = jnp.asarray(rng.standard_normal((32, 3)).astype(np.float32))
+    mesh = _mesh(size)
+    for erased in [(), (0,), (1, 2), (N_BLOCKS - 1, 5)]:
+        m = np.ones(N_BLOCKS)
+        m[list(erased)] = 0.0
+        mj = jnp.asarray(m, jnp.float32)
+        ref = np.asarray(cl.apply(wc, x, mj))[:220]
+        got = np.asarray(
+            coded_head_matvec(wc, x, mj, n_data, n_parity, mesh=mesh)
+        )[:220]
+        np.testing.assert_allclose(got, ref, rtol=0, atol=1e-5)
+
+
 def test_validate_coded_head_mesh_rejects_wrong_geometry():
     require_devices(2)
     from jax.sharding import Mesh
     from repro.sharding.policy import validate_coded_head_mesh
 
-    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
-    with pytest.raises(ValueError):
+    require_devices(3)
+    mesh = Mesh(np.array(jax.devices()[:3]), ("model",))
+    with pytest.raises(ValueError):  # 3 devices cannot hold 16 whole blocks
         validate_coded_head_mesh(mesh, N_BLOCKS, "model")
     with pytest.raises(ValueError):
-        validate_coded_head_mesh(mesh, 2, "data")
+        validate_coded_head_mesh(mesh, 3, "data")
+    for size in (1, 2, 4, 8, N_BLOCKS):  # whole blocks on every device
+        mesh = Mesh(np.array(jax.devices()[:size]), ("model",))
+        validate_coded_head_mesh(mesh, N_BLOCKS, "model")
 
 
 # --------------------------------------------------------------------------
@@ -110,6 +143,43 @@ def test_engine_mesh_sharded_head_bit_identical(coded_model):
     ref = run(None)
     got = run(_mesh())
     assert ref == got
+
+
+def test_engine_mesh_places_state_on_the_mesh(coded_model):
+    """On a 4-device head axis (4 blocks per device) the engine emits the
+    single-device tokens, and every array it steps with lives on the mesh:
+    the coded head split by blocks, everything else replicated — none left
+    on the default device alone."""
+    from jax.sharding import NamedSharding
+
+    from repro.serve import Request, ServeEngine
+
+    cfg, model, params = coded_model
+    mesh = _mesh(4)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab, 8).astype(np.int32) for _ in range(3)]
+    mask = np.ones(N_BLOCKS)
+    mask[[4, 13]] = 0.0  # two blocks on two different devices
+
+    def run(mesh):
+        eng = ServeEngine(model, params, n_slots=2, s_max=32,
+                          mask_fn=lambda: mask, mesh=mesh)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(uid=i, prompt=p.copy(), max_new_tokens=6))
+        return eng, {r.uid: r.out_tokens for r in eng.run()}
+
+    _, ref = run(None)
+    eng, got = run(mesh)
+    assert ref == got
+    devices = set(mesh.devices.flat)
+    head = eng.params["lm_head_coded"]
+    assert head.sharding.spec[0] == "model"
+    assert {s.data.shape[0] for s in head.addressable_shards} == {
+        head.shape[0] // 4
+    }
+    for leaf in jax.tree.leaves((eng.params, eng.cache, eng._last_tok)):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.device_set == devices
 
 
 def test_engine_mesh_requires_coded_config(coded_model):

@@ -11,6 +11,10 @@ It also places JAX's persistent compilation cache, in this one place: where
 it; otherwise the cache lives at ``.jax_cache/`` in the checkout.  The path
 is part of the cache key, so it is fixed: a warm cache from one run serves
 the next (a 32-layer decode step otherwise compiles from scratch each run).
+The cache key includes the programs' metadata: named scopes
+(``coded_head``, ``kv_write``) live only there, and without it a program
+compiled before a scope was added or renamed is loaded with the old
+``op_name``s, which ``ServeEngine.op_scopes()`` and a profile then show.
 """
 import os as _os
 from pathlib import Path as _Path
@@ -18,6 +22,7 @@ from pathlib import Path as _Path
 import jax as _jax
 
 _jax.config.update("jax_threefry_partitionable", True)
+_jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     _jax.config.update(

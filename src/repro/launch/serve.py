@@ -15,13 +15,44 @@ dropping the ``--parity`` slowest.
 ``--dry-run`` prints the fully-resolved serving configuration (model
 config, coded-head geometry, engine settings) and exits without building
 the model or executing a single step — the config-validation idiom.
+
+``--profile DIR`` records a ``jax.profiler`` trace of the serve loop in
+DIR: the engine's host spans (``engine.step``, ``engine.admit``,
+``engine.prefill``, ``engine.splice``, ``engine.control``,
+``engine.launch``, ``engine.sync``, ``engine.apply``) beside the device's
+operations.  At the end it prints, for each compiled step, how many of
+its operations fall under the model's named scopes
+(``ServeEngine.op_scopes()``), which is how a device operation in the
+trace is traced back to the coded head or the KV-cache write.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
+from collections import Counter
 
 import numpy as np
+
+SCOPES = ("coded_head", "kv_write")  # the model's jax.named_scopes
+
+
+def _profiled(log_dir: str | None):
+    """A profiler trace into ``log_dir`` around the serve loop, or nothing."""
+    if log_dir is None:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.trace(log_dir)
+
+
+def _print_scopes(eng) -> None:
+    """Operations per named scope of each compiled step."""
+    for prog, ops in eng.op_scopes().items():
+        n = Counter(next((s for s in SCOPES if f"/{s}/" in op), "unscoped")
+                    for op in ops.values())
+        print(f"  {prog} operations: "
+              + "  ".join(f"{k} {v}" for k, v in sorted(n.items())))
 
 
 def main() -> None:
@@ -96,6 +127,11 @@ def main() -> None:
                     help="PRNG seed (params, prompts, straggler draws)")
     ap.add_argument("--dry-run", action="store_true",
                     help="print the resolved config and exit without executing")
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="record a jax.profiler trace of the serve loop in DIR "
+                         "(the engine's engine.* spans and the device's "
+                         "operations) and print each compiled step's "
+                         "operations per named scope")
     args = ap.parse_args()
     if args.adaptive_parity and not (args.coded and args.straggler_prob > 0):
         ap.error("--adaptive-parity requires --coded and --straggler-prob > 0 "
@@ -227,12 +263,13 @@ def main() -> None:
                           scheduler=sched, clock=clock,
                           prefill_budget=args.prefill_budget,
                           macro_steps=args.macro_steps)
-        while not sched.finished:
-            if eng.macro_step() == 0:
-                nxt = sched.next_arrival()
-                if nxt is None:
-                    break
-                time.sleep(max(0.0, nxt - clock()))
+        with _profiled(args.profile):
+            while not sched.finished:
+                if eng.macro_step() == 0:
+                    nxt = sched.next_arrival()
+                    if nxt is None:
+                        break
+                    time.sleep(max(0.0, nxt - clock()))
         res = sched.results()
         dt = clock()
         n_tok = int(res["n_tokens"][np.isfinite(res["t_complete"])].sum())
@@ -251,6 +288,8 @@ def main() -> None:
                 att = res["slo_met"][sel].mean() if sel.any() else 1.0
                 print(f"  class {cls.name}: weight={cls.weight:g} "
                       f"n={int(sel.sum())} attainment {att:.1%}")
+        if args.profile:
+            _print_scopes(eng)
         return
 
     eng = ServeEngine(model, params, n_slots=args.slots, s_max=args.s_max,
@@ -261,7 +300,8 @@ def main() -> None:
         prompt = rng.integers(0, cfg.vocab, size=args.prompt_len).astype(np.int32)
         eng.submit(Request(uid=i, prompt=prompt, max_new_tokens=args.max_new))
     t0 = time.time()
-    done = eng.run()
+    with _profiled(args.profile):
+        done = eng.run()
     dt = time.time() - t0
     n_tok = sum(len(r.out_tokens) for r in done)
     syncs_per_tok = eng.sync_count / max(eng.tokens_emitted, 1)
@@ -273,6 +313,8 @@ def main() -> None:
           f"host_syncs/token={syncs_per_tok:.3f}")
     for r in done[:3]:
         print(f"  req {r.uid}: {r.out_tokens[:10]}...")
+    if args.profile:
+        _print_scopes(eng)
 
 
 if __name__ == "__main__":

@@ -162,20 +162,22 @@ def attention_decode(
     k = apply_rope(k, pos[:, None], theta)
 
     s_max = cache_k.shape[1]
-    if aligned:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), pos[0], axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), pos[0], axis=1)
-    else:
-        # masked one-hot write (NOT vmapped dynamic_update_slice): per-seq
-        # scatter positions make the SPMD partitioner fall into pathological
-        # resharding when the cache's sequence dim is sharded — the
-        # elementwise select shards trivially at the cost of rewriting the
-        # cache (decode already reads it; ~1.5x traffic, charged honestly)
-        hot = (jnp.arange(s_max)[None, :] == pos[:, None])[..., None, None]
-        cache_k = jnp.where(hot, k[:, 0][:, None].astype(cache_k.dtype), cache_k)
-        cache_v = jnp.where(hot, v[:, 0][:, None].astype(cache_v.dtype), cache_v)
+    with jax.named_scope("kv_write"):  # named in the compiled op_names
+        if aligned:
+            cache_k = jax.lax.dynamic_update_slice_in_dim(
+                cache_k, k.astype(cache_k.dtype), pos[0], axis=1)
+            cache_v = jax.lax.dynamic_update_slice_in_dim(
+                cache_v, v.astype(cache_v.dtype), pos[0], axis=1)
+        else:
+            # masked one-hot write (NOT vmapped dynamic_update_slice): per-seq
+            # scatter positions make the SPMD partitioner fall into
+            # pathological resharding when the cache's sequence dim is
+            # sharded — the elementwise select shards trivially at the cost
+            # of rewriting the cache (decode already reads it; ~1.5x
+            # traffic, charged honestly)
+            hot = (jnp.arange(s_max)[None, :] == pos[:, None])[..., None, None]
+            cache_k = jnp.where(hot, k[:, 0][:, None].astype(cache_k.dtype), cache_k)
+            cache_v = jnp.where(hot, v[:, 0][:, None].astype(cache_v.dtype), cache_v)
     mask = (jnp.arange(s_max)[None, :] <= pos[:, None])[:, None, None, None, :]
     out = _sdpa(q, cache_k.astype(dt), cache_v.astype(dt), mask)
     out = _mask_pad_heads(out, n_real)

@@ -497,17 +497,18 @@ def _last_logits(
         mask = head_mask if head_mask is not None else jnp.ones((n_blocks,), jnp.float32)
         cm = current_coded_head_mesh()
         mesh, axis = cm if cm is not None else (None, "model")
-        y = coded_head_matvec(
-            params["lm_head_coded"].astype(jnp.float32),
-            last.astype(jnp.float32).T,
-            mask,
-            n_blocks - cfg.coded_parity,
-            cfg.coded_parity,
-            mesh=mesh,
-            axis=axis,
-            kernel_mode=current_head_kernel_mode(),
-        )
-        return y[: cfg.vocab].T
+        with jax.named_scope("coded_head"):  # named in the compiled op_names
+            y = coded_head_matvec(
+                params["lm_head_coded"].astype(jnp.float32),
+                last.astype(jnp.float32).T,
+                mask,
+                n_blocks - cfg.coded_parity,
+                cfg.coded_parity,
+                mesh=mesh,
+                axis=axis,
+                kernel_mode=current_head_kernel_mode(),
+            )
+            return y[: cfg.vocab].T
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     return last.astype(jnp.float32) @ head.astype(jnp.float32)
 

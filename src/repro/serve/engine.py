@@ -50,6 +50,19 @@ one per fused step, BEFORE the block launches; the decode data plane is
 bit-identical to K scalar steps because the scalar loop already decodes
 every slot every step (inactive slots produce discarded tokens), so the
 device trajectory does not depend on mid-block slot retirement.
+
+Profiler spans: each step's host work is annotated with
+``jax.profiler.TraceAnnotation``s, recorded only while a profiler trace
+runs (``launch/serve.py --profile``): ``engine.step`` (arg ``k``) around
+a scalar step or fused block, holding ``engine.admit`` (the admission
+pass, with ``engine.prefill`` per request, args ``uid`` and ``tokens``,
+and ``engine.splice``, arg ``slots``), ``engine.control`` (one per
+decoded step), ``engine.launch`` (mask upload and dispatch),
+``engine.sync`` (the host waiting for the token read) and
+``engine.apply`` (bookkeeping).  On the device, the model's
+``coded_head`` and ``kv_write`` named scopes reach the compiled
+programs' ``op_name`` metadata; ``ServeEngine.op_scopes()`` maps each
+compiled instruction to it.
 """
 from __future__ import annotations
 
@@ -63,6 +76,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.registry import Model
+from repro.utils.hlo import op_names
+
+_span = jax.profiler.TraceAnnotation
 
 if TYPE_CHECKING:  # annotation-only: keeps the module import light
     from repro.core.adaptive import DeadlineAwareParity, ParityController
@@ -199,7 +215,8 @@ class ServeEngine:
         self.parity_events: list[dict] = []
         self._saturated_steps = 0
         self._steps = 0
-        # host-sync accounting (benchmarks/engine_bench.py reads these)
+        # host-sync accounting (read by launch/serve.py, tests and
+        # benchmarks/engine_bench.py)
         self.sync_count = 0         # device->host transfers on the hot path
         self.tokens_emitted = 0     # tokens appended to request outputs
         self.macro_blocks = 0       # fused blocks launched (K > 1)
@@ -213,6 +230,7 @@ class ServeEngine:
         self.queue: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * n_slots
         self.cache = model.init_cache(n_slots, s_max)
+        self._last_prefill: dict | None = None  # op_scopes() lowers with it
         self._last_tok = jnp.zeros(n_slots, jnp.int32)  # device-resident
         self._active = np.zeros(n_slots, bool)
         if model.cfg.coded:
@@ -289,6 +307,31 @@ class ServeEngine:
         # never read by the unmasked head)
         self._zero_xs: dict[int, Any] = {}
 
+    @property
+    def _masked(self) -> bool:
+        """Does a decode step take an erasure mask (else None)?"""
+        return self.model.cfg.coded and (
+            self.latency_fn is not None or self.mask_fn is not None
+        )
+
+    def op_scopes(self) -> dict[str, dict[str, str]]:
+        """The ``op_name`` of every operation of the compiled scalar steps,
+        keyed by program and instruction name (``reshape.65``, as a
+        profiler's ``XLA Ops`` line names it): ``{"_decode_argmax": {...},
+        "_prefill_argmax": {...}}``.  A ``jax.named_scope`` in the model
+        (``coded_head``, ``kv_write``) shows as a path segment; "" where
+        the compiler left no metadata.  Each step is lowered and compiled
+        again with the arguments it last ran with (the prefill once one
+        has run), so this is for operators and trace readers, never for
+        the hot path."""
+        mask = jnp.ones(self._n_blocks, jnp.float32) if self._masked else None
+        out = {"_decode_argmax": self._decode.lower(
+            self.params, self.cache, self._last_tok, mask)}
+        if self._last_prefill is not None:
+            out["_prefill_argmax"] = self._prefill1.lower(
+                self.params, self._last_prefill)
+        return {k: op_names(v.compile().as_text()) for k, v in out.items()}
+
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
         self.queue.append(req)
@@ -308,10 +351,12 @@ class ServeEngine:
             batch["frames"] = jnp.asarray(
                 np.zeros((1, len(req.prompt), self.model.cfg.d_model), np.float32)
             )
-        tok1, cache1 = self._prefill1(self.params, batch)
-        self._pending_splice.append((slot, cache1))
-        self._last_tok = self._last_tok.at[slot].set(tok1[0])  # device-side
-        req.out_tokens.append(int(np.asarray(tok1)[0]))
+        self._last_prefill = batch
+        with _span("engine.prefill", uid=req.uid, tokens=len(req.prompt)):
+            tok1, cache1 = self._prefill1(self.params, batch)
+            self._pending_splice.append((slot, cache1))
+            self._last_tok = self._last_tok.at[slot].set(tok1[0])  # device-side
+            req.out_tokens.append(int(np.asarray(tok1)[0]))
         self.sync_count += 1
         self.tokens_emitted += 1
         self.slots[slot] = req
@@ -353,7 +398,8 @@ class ServeEngine:
                 srcs.append(src.astype(full.dtype))
             return full.at[tuple(idx)].set(jnp.stack(srcs, axis=ax))
 
-        self.cache = jax.tree_util.tree_map_with_path(splice, self.cache, *ones)
+        with _span("engine.splice", slots=len(slot_list)):
+            self.cache = jax.tree_util.tree_map_with_path(splice, self.cache, *ones)
         self.splice_rebuilds += 1
 
     def _finish_slot(self, slot: int, req: Request, now: float | None) -> None:
@@ -384,10 +430,11 @@ class ServeEngine:
     def _refill(self, now: float | None = None) -> None:
         """One admission pass; all admitted caches land in a single
         batched splice (one tree rebuild per pass, not per request)."""
-        try:
-            self._admit_refill(now)
-        finally:
-            self._flush_splices()
+        with _span("engine.admit"):
+            try:
+                self._admit_refill(now)
+            finally:
+                self._flush_splices()
 
     def _admit_refill(self, now: float | None = None) -> None:
         if self.scheduler is not None:
@@ -584,42 +631,47 @@ class ServeEngine:
 
     def step(self) -> int:
         """One batched decode step; returns number of active sequences."""
-        now = self._clock() if self.scheduler is not None else None
-        self._refill(now)
-        if not self._active.any():
-            return 0
-        self._steps += 1
-        if self._pending_ctrl is not None:
-            # a truncated fused block already ran this step's control
-            m = self._pending_ctrl[0]
-            self._pending_ctrl = None
-        else:
-            m = self._control_step(now)
-        mask = None if m is None else jnp.asarray(m, jnp.float32)
-        # step-time measurement starts HERE: _refill's prefills (and their
-        # jit compiles) are admission work, not decode-step time
-        t_decode0 = self._clock() if self.scheduler is not None else None
-        toks_dev, self.cache = self._decode(
-            self.params, self.cache, self._last_tok, mask
-        )
-        self._last_tok = toks_dev           # feeds next step, never leaves device
-        toks = np.asarray(toks_dev)         # the ONE host transfer per step
-        self.sync_count += 1
-        t_done = None
-        if self.scheduler is not None:
-            t_done = self._clock()
-            if ("decode", 1) in self._compiled:
-                self.scheduler.observe_step(t_done - t_decode0)
+        with _span("engine.step", k=1):
+            now = self._clock() if self.scheduler is not None else None
+            self._refill(now)
+            if not self._active.any():
+                return 0
+            self._steps += 1
+            if self._pending_ctrl is not None:
+                # a truncated fused block already ran this step's control
+                m = self._pending_ctrl[0]
+                self._pending_ctrl = None
             else:
-                # first call of this jit bucket since the (re-)bind: the
-                # duration is compile time, not a step time — feeding it
-                # would poison the EW estimate and make admission reject
-                # feasible arrivals
+                with _span("engine.control"):
+                    m = self._control_step(now)
+            with _span("engine.launch"):
+                mask = None if m is None else jnp.asarray(m, jnp.float32)
+                # step-time measurement starts HERE: _refill's prefills (and
+                # their jit compiles) are admission work, not decode-step time
+                t_decode0 = self._clock() if self.scheduler is not None else None
+                toks_dev, self.cache = self._decode(
+                    self.params, self.cache, self._last_tok, mask
+                )
+            self._last_tok = toks_dev       # feeds next step, never leaves device
+            with _span("engine.sync"):
+                toks = np.asarray(toks_dev)  # the ONE host transfer per step
+            self.sync_count += 1
+            t_done = None
+            if self.scheduler is not None:
+                t_done = self._clock()
+                if ("decode", 1) in self._compiled:
+                    self.scheduler.observe_step(t_done - t_decode0)
+                else:
+                    # first call of this jit bucket since the (re-)bind: the
+                    # duration is compile time, not a step time — feeding it
+                    # would poison the EW estimate and make admission reject
+                    # feasible arrivals
+                    self._compiled.add(("decode", 1))
+            elif ("decode", 1) not in self._compiled:
                 self._compiled.add(("decode", 1))
-        elif ("decode", 1) not in self._compiled:
-            self._compiled.add(("decode", 1))
-        self._apply_step(toks, t_done)
-        return int(self._active.sum())
+            with _span("engine.apply"):
+                self._apply_step(toks, t_done)
+            return int(self._active.sum())
 
     # ------------------------------------------------------------------
     # fused macro-step decode (DESIGN.md §14)
@@ -714,9 +766,7 @@ class ServeEngine:
         model = self.model
         mesh, axis = self._mesh, self._head_axis
         kmode = self.head_kernel_mode
-        masked = self.model.cfg.coded and (
-            self.latency_fn is not None or self.mask_fn is not None
-        )
+        masked = self._masked
 
         def _decode_block(params, cache, last_tok, masks):
             def body(carry, m):
@@ -755,101 +805,106 @@ class ServeEngine:
             additionally cannot un-encode.  Both are outside the fused
             gate's steady-state envelope and documented in DESIGN.md §14.)
         """
-        now = self._clock() if self.scheduler is not None else None
-        self._refill(now)  # the K gate makes this a no-op; seam kept
-        if not self._active.any():
-            return 0
-        s0 = self._steps
-        n_events = len(self.parity_events)
-        old_decode, old_params = self._decode, self.params
-        comp_before = self._compiled
-        snaps: list[tuple] = []
-        masks: list[np.ndarray | None] = []
-        raised = False
-        for t in range(k):
-            snaps.append(self._ctrl_snapshot())
-            self._steps = s0 + t + 1  # raise events record scalar-exact steps
-            m = self._control_step(now)
-            if len(self.parity_events) > n_events:
-                raised = True
-                self._pending_ctrl = (m,)  # the post-raise step's control
-                break
-            masks.append(m)
-        self._steps = s0
-        k_exec = len(masks)
-        if raised and k_exec == 0:
-            return self.step()  # consumes the pending control immediately
-        if raised:
-            # degrade: replay the pre-raise steps through the OLD jitted
-            # scalar step (the raise re-bound self._decode to the new
-            # geometry; these steps belong to the old one)
-            executed = 0
-            for t in range(k_exec):
-                self._steps += 1
-                m = masks[t]
-                mask = None if m is None else jnp.asarray(m, jnp.float32)
-                t0 = self._clock() if self.scheduler is not None else None
-                toks_dev, self.cache = old_decode(
-                    old_params, self.cache, self._last_tok, mask
-                )
-                self._last_tok = toks_dev
-                toks = np.asarray(toks_dev)
-                self.sync_count += 1
-                t_done = None
-                if self.scheduler is not None:
-                    t_done = self._clock()
-                    if ("decode", 1) in comp_before:
-                        self.scheduler.observe_step(t_done - t0)
-                    else:
-                        comp_before.add(("decode", 1))
-                self._apply_step(toks, t_done)
-                executed += 1
-                if not self._active.any():
-                    break
+        with _span("engine.step", k=k):
+            now = self._clock() if self.scheduler is not None else None
+            self._refill(now)  # the K gate makes this a no-op; seam kept
             if not self._active.any():
-                # the batch drained before the post-raise step ran: its
-                # stashed control must not leak onto a future step, and
-                # the scalar loop would have stopped at `executed`
-                self._pending_ctrl = None
+                return 0
+            s0 = self._steps
+            n_events = len(self.parity_events)
+            old_decode, old_params = self._decode, self.params
+            comp_before = self._compiled
+            snaps: list[tuple] = []
+            masks: list[np.ndarray | None] = []
+            raised = False
+            for t in range(k):
+                snaps.append(self._ctrl_snapshot())
+                self._steps = s0 + t + 1  # raise events record scalar-exact steps
+                with _span("engine.control"):
+                    m = self._control_step(now)
+                if len(self.parity_events) > n_events:
+                    raised = True
+                    self._pending_ctrl = (m,)  # the post-raise step's control
+                    break
+                masks.append(m)
+            self._steps = s0
+            k_exec = len(masks)
+            if raised and k_exec == 0:
+                return self.step()  # consumes the pending control immediately
+            if raised:
+                # degrade: replay the pre-raise steps through the OLD jitted
+                # scalar step (the raise re-bound self._decode to the new
+                # geometry; these steps belong to the old one)
+                executed = 0
+                for t in range(k_exec):
+                    self._steps += 1
+                    m = masks[t]
+                    mask = None if m is None else jnp.asarray(m, jnp.float32)
+                    t0 = self._clock() if self.scheduler is not None else None
+                    toks_dev, self.cache = old_decode(
+                        old_params, self.cache, self._last_tok, mask
+                    )
+                    self._last_tok = toks_dev
+                    toks = np.asarray(toks_dev)
+                    self.sync_count += 1
+                    t_done = None
+                    if self.scheduler is not None:
+                        t_done = self._clock()
+                        if ("decode", 1) in comp_before:
+                            self.scheduler.observe_step(t_done - t0)
+                        else:
+                            comp_before.add(("decode", 1))
+                    self._apply_step(toks, t_done)
+                    executed += 1
+                    if not self._active.any():
+                        break
+                if not self._active.any():
+                    # the batch drained before the post-raise step ran: its
+                    # stashed control must not leak onto a future step, and
+                    # the scalar loop would have stopped at `executed`
+                    self._pending_ctrl = None
+                    self._ctrl_restore(snaps[executed])
+                return int(self._active.sum())
+            blk = self._block_fn(k)
+            fresh = ("decode", k) not in self._compiled
+            self._compiled.add(("decode", k))
+            with _span("engine.launch"):
+                if masks[0] is None:
+                    mstack = self._zero_xs.get(k)  # dummy scan xs, unmasked head
+                    if mstack is None:
+                        mstack = self._zero_xs[k] = jnp.zeros(k)
+                else:
+                    mstack = jnp.asarray(np.stack(masks), jnp.float32)
+                t0 = self._clock() if self.scheduler is not None else None
+                toks_blk, self._last_tok, self.cache = blk(
+                    self.params, self.cache, self._last_tok, mstack
+                )
+            with _span("engine.sync"):
+                toks = np.asarray(toks_blk)  # THE one host transfer for the block
+            self.sync_count += 1
+            self.macro_blocks += 1
+            t_done = None
+            dt = 0.0
+            if self.scheduler is not None:
+                t_done = self._clock()
+                dt = (t_done - t0) / k  # per-step share of the block time
+            executed = 0
+            with _span("engine.apply"):
+                for t in range(k):
+                    self._steps += 1
+                    if self.scheduler is not None and not fresh and dt > 0:
+                        # K equal observes of the block mean: same total EW mass
+                        # as the scalar loop's K per-step observes
+                        self.scheduler.observe_step(dt)
+                    self._apply_step(toks[t], t_done)
+                    executed += 1
+                    if not self._active.any():
+                        break
+            if executed < k:
+                # EOS drained the batch early: the scalar loop would have
+                # stopped here — roll back the trailing control decisions
                 self._ctrl_restore(snaps[executed])
             return int(self._active.sum())
-        blk = self._block_fn(k)
-        fresh = ("decode", k) not in self._compiled
-        self._compiled.add(("decode", k))
-        if masks[0] is None:
-            mstack = self._zero_xs.get(k)  # dummy scan xs, unmasked head
-            if mstack is None:
-                mstack = self._zero_xs[k] = jnp.zeros(k)
-        else:
-            mstack = jnp.asarray(np.stack(masks), jnp.float32)
-        t0 = self._clock() if self.scheduler is not None else None
-        toks_blk, self._last_tok, self.cache = blk(
-            self.params, self.cache, self._last_tok, mstack
-        )
-        toks = np.asarray(toks_blk)  # THE one host transfer for the block
-        self.sync_count += 1
-        self.macro_blocks += 1
-        t_done = None
-        dt = 0.0
-        if self.scheduler is not None:
-            t_done = self._clock()
-            dt = (t_done - t0) / k  # per-step share of the block time
-        executed = 0
-        for t in range(k):
-            self._steps += 1
-            if self.scheduler is not None and not fresh and dt > 0:
-                # K equal observes of the block mean: same total EW mass
-                # as the scalar loop's K per-step observes
-                self.scheduler.observe_step(dt)
-            self._apply_step(toks[t], t_done)
-            executed += 1
-            if not self._active.any():
-                break
-        if executed < k:
-            # EOS drained the batch early: the scalar loop would have
-            # stopped here — roll back the trailing control decisions
-            self._ctrl_restore(snaps[executed])
-        return int(self._active.sum())
 
     def macro_step(self) -> int:
         """One macro-step: a fused K-step block at batch-full steady

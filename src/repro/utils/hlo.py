@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 
 __all__ = [
     "HW_V5E",
+    "op_names",
     "CollectiveStats",
     "collective_bytes",
     "analyze_hlo",
@@ -180,6 +181,40 @@ def _parse_computations(text: str) -> dict[str, list[tuple]]:
             ops = re.findall(r"%([\w.\-]+)", operands)
             cur.append((name, shape_text.strip(), op, ops, attrs))
     return comps
+
+
+_NAME = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_INLINED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+
+
+def op_names(text: str) -> dict[str, str]:
+    """Instruction name -> its ``op_name`` metadata ("" where it carries
+    none) for every instruction of one optimized HLO module that runs as
+    an operation of its own: fusion bodies and reducers are left out, as a
+    fusion runs as one operation under the fusion's name and with its
+    root's ``op_name``.  ``jax.named_scope``s show as path segments of
+    the ``op_name`` (``jit(f)/coded_head/dot_general``)."""
+    comps: dict[str, dict[str, str]] = {}
+    inlined: set[str] = set()
+    cur: dict[str, str] | None = None
+    for raw in text.splitlines():
+        line = _COMMENT.sub("", raw).rstrip()
+        if cur is None:
+            m = _COMP_HEAD.match(line.strip())
+            if m:
+                cur = comps.setdefault(m.group(2), {})
+            continue
+        if line.strip() == "}":
+            cur = None
+            continue
+        m = _NAME.match(line)
+        if m:
+            meta = _OP_NAME.search(line)
+            cur[m.group(1)] = meta.group(1) if meta else ""
+            inlined.update(_INLINED.findall(line))
+    return {name: op for comp, instrs in comps.items() if comp not in inlined
+            for name, op in instrs.items()}
 
 
 def analyze_hlo(text: str) -> HloCosts:

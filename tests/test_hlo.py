@@ -1,4 +1,6 @@
 """HLO analyzer: trip-count expansion, dot FLOPs, collective accounting."""
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -124,3 +126,22 @@ def test_roofline_terms():
     assert rl.mfu_bound == pytest.approx(0.5)
     rl2 = roofline(flops=1e12, hbm_bytes=819e9 * 3, wire_bytes=0)
     assert rl2.dominant == "memory"
+
+
+def test_op_names_follow_named_scopes():
+    """Every instruction of the entry computation maps to its ``op_name``;
+    a named scope shows as a path segment; fusion bodies and reducers,
+    which never run as operations of their own, are left out."""
+    from repro.utils.hlo import op_names
+
+    def f(w, x):
+        with jax.named_scope("coded_head"):
+            y = jnp.dot(w, x) * 2
+        return jnp.argmax(y + 1)
+
+    text = jax.jit(f).lower(jnp.ones((64, 32)), jnp.ones(32)).compile().as_text()
+    names = op_names(text)
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert set(names) == set(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) =", entry, re.M))
+    assert any("/coded_head/" in op for op in names.values())

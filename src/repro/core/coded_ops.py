@@ -37,6 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 __all__ = [
     "block_mds_generator",
     "block_mds_generator_np",
+    "block_rows",
     "CodedLinear",
     "encode_blocks",
     "decode_blocks",
@@ -131,15 +132,30 @@ def block_mds_generator(
     return jnp.asarray(block_mds_generator_np(n_blocks, n_data, n_seeds), dtype=dtype)
 
 
+def block_rows(out: int, n_data: int, dtype=jnp.float32) -> int:
+    """Rows per code block: ``ceil(out / n_data)`` rounded up to the
+    sublane tile of ``dtype`` (8 rows of 4 bytes, 16 of 2, 32 of 1).
+
+    Blocks that start on tile boundaries let XLA view the stored
+    ``[n_blocks * br, in]`` weight as ``[n_blocks, br, in]`` in place; an
+    unaligned ``br`` makes the per-step block matmul relayout the whole
+    weight first.  The extra rows are zero padding, like the ceil's.
+    """
+    tile = 8 * max(1, 4 // np.dtype(dtype).itemsize)
+    rows = -(-out // n_data)  # ceil
+    return -(-rows // tile) * tile
+
+
 def encode_blocks(w: jnp.ndarray, n_data: int, n_parity: int) -> jnp.ndarray:
     """Encode weight rows into (n_data + n_parity) blocks.
 
-    w [out, in]  ->  [n_blocks * ceil(out/n_data), in]  (row-padded).
-    Block j (j >= n_data) = sum_i B[j, i] * block_i.  Done once, offline
-    (paper: Â = H A is pre-stored), so plain einsum is fine here.
+    w [out, in]  ->  [n_blocks * br, in], br = ``block_rows(out, n_data,
+    w.dtype)`` (zero row padding).  Block j (j >= n_data) = sum_i B[j, i] *
+    block_i.  Done once, offline (paper: Â = H A is pre-stored), so plain
+    einsum is fine here.
     """
     out, inner = w.shape
-    br = -(-out // n_data)  # ceil
+    br = block_rows(out, n_data, w.dtype)
     pad = n_data * br - out
     wp = jnp.pad(w, ((0, pad), (0, 0)))
     blocks = wp.reshape(n_data, br, inner)
@@ -232,7 +248,8 @@ class CodedLinear:
 
     @property
     def block_rows(self) -> int:
-        return -(-self.out_features // self.n_data)
+        """Rows per block of a float32 encode (:func:`block_rows`)."""
+        return block_rows(self.out_features, self.n_data)
 
     def encode(self, w: jnp.ndarray) -> jnp.ndarray:
         return encode_blocks(w, self.n_data, self.n_parity)
@@ -288,13 +305,14 @@ class CodedLinear:
                                         **params)
                 return y[: self.out_features]
         # rows sharded -> each device computes its block
+        br = w_coded.shape[0] // self.n_blocks
         y_coded = jnp.matmul(w_coded, x, precision=EXACT)
-        y_coded = y_coded.reshape(self.n_blocks, self.block_rows, -1)
+        y_coded = y_coded.reshape(self.n_blocks, br, -1)
         if kernel_mode == "svd":
             y = decode_blocks_svd(y_coded, mask, self.n_data, self.n_parity)
         else:
             y = decode_blocks(y_coded, mask, self.n_data, self.n_parity)
-        y = y.reshape(self.n_data * self.block_rows, -1)
+        y = y.reshape(self.n_data * br, -1)
         return y[: self.out_features]
 
 

@@ -47,6 +47,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 
+from repro.core.coded_ops import block_rows
+
 __all__ = [
     "HostHardware",
     "CPU_HOST",
@@ -199,7 +201,7 @@ def coded_linear_costs(
     on CPU (partials round-trip once, but no mask-multiply / lut machinery).
     """
     nb = n_data + n_parity
-    br = -(-out // n_data)
+    br = block_rows(out, n_data)  # the stored float32 shape
     rows = nb * br
     gemm = 2.0 * rows * inner * batch
     dec = 2.0 * n_data * nb * br * batch
@@ -417,7 +419,7 @@ def tile_params(op: str, **geom) -> dict:
     elif op in ("coded_linear", "coded_matvec_decode"):
         if op == "coded_linear":
             nb = geom["n_data"] + geom["n_parity"]
-            br = -(-geom["out"] // geom["n_data"])
+            br = block_rows(geom["out"], geom["n_data"])
             p = choose_decode_tiles(br, geom["inner"], geom["batch"],
                                     nb, geom["n_data"])
         else:

@@ -198,13 +198,14 @@ def encode_blocks_device(
     The serving analogue of ``encode_rows``: ``coded_ops.encode_blocks``'s
     einsum, restructured as  B [n_blocks, n_data] @ blocks [n_data, br*in]
     so a ParityController-driven parity re-encode runs on device without a
-    host round-trip.  w [out, in] -> [(n_data+n_parity)*br, in] fp32.
+    host round-trip.  w [out, in] -> [(n_data+n_parity)*br, in] fp32, with
+    ``encode_blocks``'s tile-aligned ``br``.
     """
-    from repro.core.coded_ops import block_mds_generator_np
+    from repro.core.coded_ops import block_mds_generator_np, block_rows
 
     w = jnp.asarray(w)
     out, inner = w.shape
-    br = -(-out // n_data)  # ceil
+    br = block_rows(out, n_data, w.dtype)
     wp = jnp.pad(w, ((0, n_data * br - out), (0, 0)))
     blocks = wp.reshape(n_data, br * inner)
     b = jnp.asarray(block_mds_generator_np(n_data + n_parity, n_data), jnp.float32)

@@ -2,11 +2,13 @@
 import itertools
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from repro.core.coded_ops import (
     CodedLinear,
     block_mds_generator,
+    block_rows,
     bpcc_batched_matvec,
     encode_blocks,
     row_coded_matvec,
@@ -53,8 +55,74 @@ def test_coded_linear_full_mask_systematic():
 def test_encode_blocks_systematic_prefix():
     w = np.arange(24, dtype=np.float32).reshape(12, 2)
     coded = np.asarray(encode_blocks(jnp.asarray(w), n_data=4, n_parity=2))
-    assert coded.shape == (18, 2)  # 6 blocks x 3 rows
+    assert coded.shape == (48, 2)  # 6 blocks x 8 rows (3 rounded to the tile)
     assert np.allclose(coded[:12], w)  # systematic prefix intact
+    assert not coded[12:32].any()  # the data blocks' padding rows are zero
+
+
+@pytest.mark.parametrize("out, n_data, dtype, want", [
+    (151_552, 14, jnp.float32, 10_832),   # glm4-9b head: ceil 10826
+    (151_552, 13, jnp.float32, 11_664),   # after a parity raise: ceil 11658
+    (32_064, 14, jnp.float32, 2_296),     # phi3-mini head: ceil 2291
+    (512, 14, jnp.float32, 40),           # ceil 37
+    (112, 14, jnp.float32, 8),            # already aligned: unchanged
+    (1, 14, jnp.float32, 8),
+    (100, 12, jnp.bfloat16, 16),          # 2-byte tile is 16 rows
+    (100, 3, jnp.int8, 64),               # 1-byte tile is 32 rows: ceil 34
+])
+def test_block_rows_rounds_the_ceil_up_to_the_dtype_tile(out, n_data, dtype, want):
+    tile = 8 * (4 // jnp.dtype(dtype).itemsize)
+    ceil = -(-out // n_data)
+    br = block_rows(out, n_data, dtype)
+    assert br == want
+    assert br % tile == 0 and ceil <= br < ceil + tile  # the least such multiple
+
+
+@pytest.mark.parametrize("out", [57, 100, 112, 8 * 14 * 3 + 1])
+def test_coded_linear_block_rows_is_the_stored_block_height(out):
+    cl = CodedLinear(n_data=14, n_parity=2, out_features=out)
+    wc = cl.encode(jnp.zeros((out, 4), jnp.float32))
+    assert cl.block_rows == wc.shape[0] // cl.n_blocks
+    assert wc.shape[0] == cl.n_blocks * cl.block_rows
+
+
+def test_exact_recovery_with_tile_padding_under_every_erasure():
+    """57 rows over 14 data blocks: the ceil pads 13 rows, the tile 55.
+    Every pattern of at most n_parity erased blocks still recovers W x,
+    and the padding rows of the data blocks are zero."""
+    n_data, n_parity, out = 14, 2, 57
+    cl = CodedLinear(n_data=n_data, n_parity=n_parity, out_features=out)
+    br = cl.block_rows
+    assert out % n_data and n_data * br - out > n_data * -(-out // n_data) - out
+    rng = np.random.default_rng(7)
+    w = rng.standard_normal((out, 24)).astype(np.float32)
+    wc = cl.encode(jnp.asarray(w))
+    assert not np.asarray(wc)[out:n_data * br].any()
+    x = rng.standard_normal((24, 5)).astype(np.float32)
+    ref = w @ x
+    n_blocks = n_data + n_parity
+    for k in range(n_parity + 1):
+        for pat in itertools.combinations(range(n_blocks), k):
+            m = np.ones(n_blocks, np.float32)
+            m[list(pat)] = 0.0
+            y = np.asarray(cl.apply(wc, jnp.asarray(x), jnp.asarray(m)))
+            assert y.shape == ref.shape
+            np.testing.assert_allclose(y, ref, rtol=0, atol=1e-3 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("n_data, n_parity", [(14, 2), (13, 3)])
+def test_encode_blocks_device_keeps_the_tile_aligned_shape(mode, n_data, n_parity):
+    """The engine's parity top-up re-encodes 14+2 as 13+3 on device: the
+    same aligned block height and values as the offline encode."""
+    from repro.kernels.ops import encode_blocks_device
+
+    rng = np.random.default_rng(8)
+    w = rng.standard_normal((150, 16)).astype(np.float32)
+    want = np.asarray(encode_blocks(jnp.asarray(w), n_data, n_parity))
+    got = np.asarray(encode_blocks_device(w, n_data, n_parity, mode=mode))
+    assert got.shape == want.shape == (16 * block_rows(150, n_data), 16)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * np.abs(want).max())
 
 
 def test_bpcc_batched_matvec_arrival_mask():

@@ -10,8 +10,10 @@ Timeline of a run (seconds on the bench's clock):
 
 * set-up: weights, the engine, then warm groups: 1, 2, ... requests
   admitted together with every prompt length of the mix, so every prefill
-  length and every admission count the window can meet compiles here;
-  then ``warm_s`` of the cell's own traffic;
+  length and every admission count the window can meet compiles here,
+  and a closed loop admits its first wave, every slot at once;
+  then what set-up made is frozen out of the garbage collector's reach
+  (``settle``), and ``warm_s`` of the cell's own traffic runs;
 * the window: ``--seconds`` of the same traffic, measured;
 * open loops drain: arrivals go on, until every request due in the window
   has finished or ``drain_s`` has passed (unfinished ones have failed);
@@ -42,6 +44,8 @@ __all__ = ["run", "Run", "TRACE_S"]
 
 TRACE_S = 3.0           # traced seconds at the end of the window
 PROBE_CALLS = 32        # bench-driven calls of a kernel in a traced probe
+SPIN_S = 1e-3           # the last stretch of a wait for an arrival reads the clock
+SLOW_X = 4.0            # a window step this many times the median is a stall
 
 
 @dataclass
@@ -83,7 +87,7 @@ class Run:
     window: tuple[float, float]
     recs: list[Rec]
     steps: list[Step]
-    shape: cost.DenseShape
+    n_blocks: int                     # coded head's blocks (0: plain head)
     params: Any                       # ShapeDtypeStruct tree of the weights
     cache: Any                        # ShapeDtypeStruct tree of the cache
     device_kind: str
@@ -130,6 +134,27 @@ def _model_config(cfg: dict):
 
 def _sds(tree):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def settle() -> None:
+    """The end of set-up: collect, then move what set-up made to the
+    permanent generation, as a server does once it has loaded, so that a
+    collection in the window scans only what the window makes and not the
+    whole heap of imports, programs and weights.  ``gc.unfreeze()`` undoes
+    it once the window is over."""
+    gc.collect()
+    gc.freeze()
+
+
+def sleep_until(clock, t: float) -> None:
+    """Return at ``clock() >= t``, within microseconds: the OS sleep can
+    wake late, so it ends ``SPIN_S`` short of ``t`` and the clock is read
+    for the rest."""
+    d = t - clock() - SPIN_S
+    if d > 0:
+        time.sleep(d)
+    while clock() < t:
+        pass
 
 
 class Ledger:
@@ -242,6 +267,7 @@ def _drive_open(gen, model, params, cfg, mix, seed, seconds, mask_fn, tracer_at,
         while led.step(clock) > 0 or sched.pending(clock()) > 0:
             pass
     clock.virtual = float(len(groups))
+    settle()
     clock.go_real()
     t0, t1 = window
     tracer = tracer_at(t0, t1)
@@ -265,7 +291,7 @@ def _drive_open(gen, model, params, cfg, mix, seed, seconds, mask_fn, tracer_at,
             if nxt is None:
                 break
             with jax.profiler.TraceAnnotation("bench.wait"):
-                time.sleep(max(0.0, min(nxt, t_end) - clock()))
+                sleep_until(clock, min(nxt, t_end))
     marks.setdefault("window_end", log.mark())
     for r in recs:
         sr = sched.requests[r.uid]
@@ -297,6 +323,16 @@ def _drive_closed(gen, model, params, cfg, mix, seed, seconds, mask_fn,
             pass
     stream = gen.stream(mix, seed, vocab, n_slots)
     depth = n_slots + int(mix["queue_extra"])
+
+    def refill() -> None:
+        while len(eng.queue) < depth:
+            submit(next(stream))
+
+    # the first wave fills every slot at once, a count past the warm
+    # groups': its admission is set-up, so its programs load here
+    refill()
+    led.step(clock)
+    settle()
     t_warm = clock()
     t0 = t_warm + mix["warm_s"]
     t1 = t0 + seconds
@@ -311,8 +347,7 @@ def _drive_closed(gen, model, params, cfg, mix, seed, seconds, mask_fn,
             tracer.poll(now)
         if now >= t1:
             break
-        while len(eng.queue) < depth:
-            submit(next(stream))
+        refill()
         led.step(clock)
     marks["window_end"] = log.mark()
     by_uid = {r.uid: r for r in eng.completed}
@@ -370,22 +405,20 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     from repro.models.config import coded_blocks
     from repro.models.registry import build_model
 
-    from reference import seed_key
-
     t_run = time.monotonic()
     bench = bench or spec.load()
     overrides = overrides or {}
     cell = bench.cell(workload)
     cfg = {**bench.config(cell["config"]), **overrides.get("config", {})}
+    bench.reference(cfg)  # a configuration that names none fails before set-up
     mix = {**bench.traffic(cell["traffic"]), **overrides.get("traffic", {})}
     log = CompileLog()
     dev = jax.devices()[0]
     mcfg = _model_config(cfg)
     model = build_model(mcfg)
     n_blocks = coded_blocks(mcfg) if mcfg.coded else 0
-    params = jax.block_until_ready(jax.jit(model.init)(seed_key(seed)))
+    params = jax.block_until_ready(jax.jit(model.init)(traffic.seed_key(seed)))
     t_weights = time.monotonic()
-    shape = cost.DenseShape.of(cfg, n_blocks)
     mask_fn = (traffic.erasure_fn(bench, mix, n_blocks, mcfg.coded_parity, seed)
                if mcfg.coded else None)
     gen = bench.generator(mix["kind"])
@@ -416,17 +449,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     peak = stats.get("peak_bytes_in_use")
     params_sds, cache_sds = _sds(params), _sds(eng.cache)
     del eng, params, probes
+    gc.unfreeze()
     gc.collect()
 
     finished = [r for r in recs if r.section != "warm0" and r.done and r.times
                 and window[0] <= r.times[-1] <= window[1] + mix.get("drain_s", 0.0)]
-    checks, correct, diag = check.run_check(cfg, mix, seed, finished, control)
+    checks, correct, diag = check.run_check(bench, cfg, mix, seed, finished, control)
 
     t = None
     if tracer and tracer.traced:
         t = tr.load(tr.find_xplane(str(trace_dir / "window")))
         shutil.rmtree(trace_dir, ignore_errors=True)
-    r = Run(cell, cfg, mix, seconds, setup_s, window, recs, steps, shape,
+    r = Run(cell, cfg, mix, seconds, setup_s, window, recs, steps, n_blocks,
             params_sds, cache_sds, dev.device_kind,
             tracer.traced if tracer else None, t, probe_out)
 
@@ -464,6 +498,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     out["compiles"] = {k: _between(marks, a, b) for k, a, b in (
         ("set-up", None, "warm"), ("warm", "warm", "window"),
         ("window", "window", "window_end"))}
+    diag["slow_steps"] = _slow_steps(r)
     diag["setup_parts_s"] = {
         "start_to_run": t_run - t_process, "weights": t_weights - t_run,
         "engine_and_warm_groups": marks["t_warm"] - t_weights,
@@ -471,6 +506,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     out["diag"] = diag
     out["checks"] = checks
     return out
+
+
+def _slow_steps(r: Run) -> dict | None:
+    """Window steps that took more than ``SLOW_X`` times the median step:
+    the runtime's late completion notices, not admissions of a few
+    requests (PERF.md)."""
+    d = np.array([s.t1 - s.t0 for s in r.steps_in(r.window)])
+    if d.size == 0:
+        return None
+    slow = d[d > SLOW_X * np.median(d)]
+    return {"median_ms": 1e3 * float(np.median(d)), "n": int(slow.size),
+            "s": float(slow.sum()), "max_ms": 1e3 * float(d.max())}
 
 
 def _between(marks: dict, a: str | None, b: str) -> dict | None:

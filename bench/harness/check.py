@@ -2,12 +2,14 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the longest
-one, goes through ``bench/reference.py``: a teacher-forced float32 forward
-over each prompt and its served tokens.  The number compared is the widest
-gap by which a served token's logit lies below the reference's best at its
-position (greedy decoding serves the argmax, so a sound program's gaps are
-rounding ties).  Beside it, every finished request must carry exactly its
-token budget of in-vocabulary tokens.
+one, goes through the reference that the configuration names
+(``bench/references/<reference>.py``, ``harness/spec.py``): a
+teacher-forced float32 forward over each prompt and its served tokens.
+The number compared is the widest gap by which a served token's logit
+lies below the reference's best at its position (greedy decoding serves
+the argmax, so a sound program's gaps are rounding ties).  Beside it,
+every finished request must carry exactly its token budget of
+in-vocabulary tokens.
 
 Each number has a limit of its own: ``limits`` in the configuration file,
 set from the program's readings over a dozen seeds and the control's
@@ -48,15 +50,14 @@ def judge(checks: dict) -> bool:
     return all(c["value"] <= c["limit"] for c in checks.values())
 
 
-def run_check(cfg: dict, mix: dict, seed: int, finished: list,
+def run_check(bench, cfg: dict, mix: dict, seed: int, finished: list,
               control: bool = False) -> tuple[dict, bool, dict]:
     """(numbers compared with their limits, correct, diagnostics).  With
     ``control`` the control, the reference one precision step below in the
     program's place, is judged on the same sample by the same limits: the
     diagnostics hold its numbers (``control_checks``) and its verdict
     (``control_correct``), which has to come out false."""
-    from reference import logit_gaps
-
+    logit_gaps = bench.reference(cfg).logit_gaps
     vocab = cfg["vocab"]
     malformed = sum(1 for r in finished
                     if len(r.tokens) != r.max_new

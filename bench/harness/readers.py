@@ -7,16 +7,20 @@ import numpy as np
 
 from . import trace as tr
 
-__all__ = ["p95", "due_in_window", "idle_share", "module_mean_ms"]
+__all__ = ["nearest_rank", "p95", "due_in_window", "idle_share", "module_mean_ms"]
 
 
-def p95(values) -> float | None:
-    """The 95th percentile by nearest rank: the smallest value with at
-    least 95% of the sample at or below it."""
+def nearest_rank(values, q: float) -> float | None:
+    """The ``q`` quantile by nearest rank: the smallest value with at
+    least ``q`` of the sample at or below it."""
     v = np.sort(np.asarray(values, np.float64))
     if v.size == 0:
         return None
-    return float(v[math.ceil(0.95 * v.size) - 1])
+    return float(v[math.ceil(q * v.size) - 1])
+
+
+def p95(values) -> float | None:
+    return nearest_rank(values, 0.95)
 
 
 def due_in_window(run) -> list:

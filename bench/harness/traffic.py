@@ -28,6 +28,8 @@ from statistics import NormalDist
 
 import numpy as np
 
+import jax
+
 __all__ = [
     "Request",
     "quantiles",
@@ -37,12 +39,21 @@ __all__ = [
     "warm_groups",
     "erasure_fn",
     "rng",
+    "seed_key",
 ]
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
     """Independent generator per (seed, stream); any whole-number seed."""
     return np.random.default_rng([seed % 2**64, stream])
+
+
+def seed_key(seed: int):
+    """A JAX key from any whole-number seed (more than 32 bits too): the
+    program's weights and every reference's are drawn from it."""
+    s = seed % 2**64
+    key = jax.random.key(s & 0xFFFFFFFF)
+    return jax.random.fold_in(key, s >> 32)
 
 
 @dataclass

@@ -13,7 +13,7 @@ def read(run):
     p = run.probes.get("coded_head")
     if not p or not p["seconds"]:
         return None
-    flops, nbytes = cost.coded_head_cost(p["w"], p["batch"], run.shape.n_data,
-                                         run.shape.n_parity)
+    shp = cost.DenseShape.of(run.cfg, run.n_blocks)
+    flops, nbytes = cost.coded_head_cost(p["w"], p["batch"], shp.n_data, shp.n_parity)
     least = cost.least_seconds(flops, nbytes, run.peaks)
     return 100.0 * least / float(np.mean(p["seconds"]))

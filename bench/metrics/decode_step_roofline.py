@@ -13,6 +13,7 @@ def read(run):
     steps = [s for s in run.steps_in(run.traced) if s.decode_pos] if run.traced else []
     if dev_ms is None or not steps:
         return None
+    shp = cost.DenseShape.of(run.cfg, run.n_blocks)
     least = [cost.least_seconds(*cost.decode_step_cost(
-        run.params, run.cache, run.shape, s.decode_pos), run.peaks) for s in steps]
+        run.params, run.cache, shp, s.decode_pos), run.peaks) for s in steps]
     return 100.0 * float(np.mean(least)) / (dev_ms * 1e-3)

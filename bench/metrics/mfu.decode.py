@@ -6,7 +6,7 @@ from harness import cost
 
 
 def read(run):
-    shp = run.shape
+    shp = cost.DenseShape.of(run.cfg, run.n_blocks)
     mm = cost.layer_matmul_params(run.params)
     flops = 0.0
     for s in run.steps:

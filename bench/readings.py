@@ -5,10 +5,10 @@
 One process, one short run per seed at the cell's own load.  Each run
 checks its finished requests as the benchmark does and, on the same
 sample, reads the control: the reference one precision step below the
-configuration (``bench/reference.py``).  One JSON line per seed, then the
-lower reading (the largest gap of the program's sound runs) and the upper
-reading (the smallest gap of the control).  The benchmark's own runs never
-read the control.
+configuration (the module it names, ``bench/references/<reference>.py``).
+One JSON line per seed, then the lower reading (the largest gap of the
+program's sound runs) and the upper reading (the smallest gap of the
+control).  The benchmark's own runs never read the control.
 """
 import time
 

@@ -37,20 +37,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Dims", "seed_key", "logit_gaps", "last_logits"]
+from harness.traffic import seed_key
+
+__all__ = ["Dims", "logit_gaps", "last_logits"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 HIGH = jax.lax.Precision.HIGH
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 HEAD_ROWS = 256  # rows of the head's logits computed at once
-
-
-def seed_key(seed: int):
-    """A JAX key from any whole-number seed (more than 32 bits too)."""
-    s = seed % 2**64
-    key = jax.random.key(s & 0xFFFFFFFF)
-    return jax.random.fold_in(key, s >> 32)
 
 
 @dataclass(frozen=True)

@@ -1,5 +1,6 @@
-"""The benchmark's tests import its harness and reference from ``bench/``
-and the system under test from ``src/``, as ``bench/run.py`` does."""
+"""The benchmark's tests import its harness from ``bench/`` (and the
+references through it, ``harness/spec.py``) and the system under test
+from ``src/``, as ``bench/run.py`` does."""
 import sys
 from pathlib import Path
 

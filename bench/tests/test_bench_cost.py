@@ -23,14 +23,15 @@ def test_decode_step_by_hand():
     flops, nbytes = cost.decode_step_cost(params, cache, shp, [3, 10])
     # matmuls: 2 layers x (4 x 64*4*16 attention + 3 x 64*128 mlp) = 81920
     # params, 2 flops each for 2 tokens; attention over 4 + 11 keys, 4 heads
-    # of 16, 2 layers; the coded head: 14 of 16 blocks of 37 rows of 64
-    assert flops == 2 * 81920 * 2 + 4 * 2 * 4 * 16 * 15 + 2 * 518 * 64 * 2
+    # of 16, 2 layers; the coded head: 14 of 16 blocks of 40 rows of 64
+    # (ceil(512 / 14) = 37 rows, stored rounded up to the float32 tile of 8)
+    assert flops == 2 * 81920 * 2 + 4 * 2 * 4 * 16 * 15 + 2 * 560 * 64 * 2
     # bfloat16 matmul weights, float32 norms (2 x [2, 64] + [64]), keys and
     # values of 15 attended and 2 written positions (2 layers x 4 heads x 16
     # x bf16, k and v), 2 embedding rows, 14/16 of the float32 coded head
     # plus its input and output columns
     assert nbytes == (81920 * 2 + 2 * 2 * 64 * 4 + 64 * 4 + 17 * 2 * 2 * 4 * 16 * 2
-                      + 2 * 64 * 2 + 592 * 64 * 4 * 14 / 16 + (64 + 518) * 2 * 4)
+                      + 2 * 64 * 2 + 640 * 64 * 4 * 14 / 16 + (64 + 560) * 2 * 4)
 
 
 def test_counts_follow_the_dtype():

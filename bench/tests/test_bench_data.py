@@ -1,15 +1,19 @@
 """``BENCHMARK.json`` against its files: every name resolves, every metric
 is reported where it should be, and new cells are only files plus entries."""
+import filecmp
 import json
 import os
 import re
 import shutil
 import subprocess
 import sys
+import time
+from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
-from harness import spec
+from harness import cell, check, cost, spec
 
 ROOT = spec.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -119,6 +123,115 @@ def test_a_new_cell_is_files_plus_entries(tmp_path):
     assert [r.t_due for r in reqs] == [2.0, 3.0, 4.0] and window == (2.0, 5.0)
     assert [m["name"] for m in bench.metrics("phi3-chat-slow", "per_layer")] == ["engine.splices"]
     assert bench.reader("engine.splices").read(None) == 1.0
+
+
+STUB_REFERENCE = """
+import numpy as np
+
+CALLS = []
+
+
+def logit_gaps(cfg, seed, prompts, served, s_pad, control=False):
+    CALLS.append({"config": cfg["name"], "seed": seed, "requests": len(prompts),
+                  "s_pad": s_pad, "control": control})
+    gaps = [np.zeros(len(t), np.float32) for t in served]
+    return gaps, (gaps if control else None)
+
+
+def last_logits(cfg, seed, seqs, s_pad):
+    return np.zeros((len(seqs), cfg["vocab"]), np.float32)
+"""
+# An attention-free family (no heads): what a configuration of a second
+# family brings, at a size the CPU runs in seconds.
+SSM = {"name": "ssm-tiny", "family": "ssm", "reference": "stub", "n_layers": 2,
+       "d_model": 64, "n_heads": 0, "n_kv_heads": 0, "d_ff": 0, "vocab": 512,
+       "ssm_state": 16, "ssm_head_dim": 8, "ssm_chunk": 16, "param_dtype": "float32",
+       "dtype": "float32", "coded": True, "coded_parity": 2,
+       "engine": {"n_slots": 4, "s_max": 96, "macro_steps": 1, "head_kernel_mode": None},
+       "limits": {"max_logit_gap": 0.04}}
+
+
+def _root_with_new_family(tmp_path, cfg, dirs=("harness", "references")):
+    """A checkout holding ``BENCHMARK.json`` and the named ``bench/`` dirs,
+    plus a configuration of a new family, its stub reference and a cell
+    on the chat mix: new files and entries only."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    for d in dirs:
+        shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench" / "references" / "stub.py").write_text(STUB_REFERENCE)
+    (tmp_path / "bench" / "configs").mkdir(exist_ok=True)
+    (tmp_path / "bench" / "configs" / "ssm-tiny.json").write_text(json.dumps(cfg))
+    doc = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    doc["configs"].append({"name": "ssm-tiny", "source": "https://example.org",
+                           "file": "bench/configs/ssm-tiny.json", "reduced": [],
+                           "why": "x"})
+    doc["workloads"].append({"name": "ssm-chat", "config": "ssm-tiny",
+                             "traffic": "chat", "chips": 1, "why": "x"})
+    for m in doc["end_to_end"]:
+        if m["name"] in ("itl_p95_ms", "ttft_p90_ms"):
+            m["workloads"].append("ssm-chat")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    return spec.load(tmp_path)
+
+
+def _unedited(tmp_path, *dirs):
+    for d in dirs:
+        cmp = filecmp.dircmp(ROOT / "bench" / d, tmp_path / "bench" / d,
+                             ignore=["__pycache__"])
+        assert not cmp.diff_files and not cmp.left_only, (d, cmp.diff_files)
+
+
+def test_a_reference_is_a_new_file(tmp_path):
+    """A configuration names a reference module that is a new file, and
+    ``run_check`` calls that module, with no harness file edited."""
+    bench = _root_with_new_family(tmp_path, SSM)
+    cfg = bench.config("ssm-tiny")
+    served = [SimpleNamespace(uid=u, n_prompt=n, prompt=np.zeros(n, np.int32),
+                              tokens=[1] * k, max_new=k)
+              for u, (n, k) in enumerate([(16, 5), (32, 9), (16, 3)])]
+    mix = spec.load().traffic("chat")
+    checks, correct, diag = check.run_check(bench, cfg, mix, 7, served, control=True)
+    stub = bench.reference(cfg)
+    assert stub.__file__ == str(tmp_path / "bench" / "references" / "stub.py")
+    assert stub.CALLS == [{"config": "ssm-tiny", "seed": 7,
+                           "requests": min(3, mix["check_requests"]),
+                           "s_pad": check.padded_length(mix), "control": True}]
+    assert correct and checks["max_logit_gap"]["value"] == 0.0
+    assert diag["checked_tokens"] == 17
+    _unedited(tmp_path, "harness", "references")
+
+
+def test_a_configuration_without_a_reference_is_refused(tmp_path):
+    cfg = {k: v for k, v in SSM.items() if k != "reference"}
+    bench = _root_with_new_family(tmp_path, cfg)
+    with pytest.raises(KeyError, match='names no "reference"'):
+        bench.reference(bench.config("ssm-tiny"))
+    with pytest.raises(KeyError, match='names no "reference"'):
+        cell.run("ssm-chat", 3, 1.0, False, t_process=time.monotonic(), bench=bench)
+
+
+def test_a_family_without_heads_runs_end_to_end(tmp_path, monkeypatch):
+    """Set-up, the window and the check of a cell whose configuration has
+    no attention heads (``n_heads`` 0) never build the dense family's
+    shape: only the readers that count dense attention do."""
+    def refuse(*a, **kw):
+        raise AssertionError("DenseShape built for a family without heads")
+
+    monkeypatch.setattr(cost.DenseShape, "of", refuse)
+    bench = _root_with_new_family(tmp_path, SSM, dirs=("harness", "references",
+                                                       "traffic", "metrics"))
+    mix = {"rate_per_s": 8.0, "warm_s": 0.3, "drain_s": 5, "check_requests": 4,
+           "prompt": {"median": 24, "sigma": 0.5, "min": 16, "max": 48, "round_to": 16},
+           "output": {"median": 12, "sigma": 0.3, "min": 8, "max": 20}}
+    out = cell.run("ssm-chat", 2**33 + 5, 1.0, False, t_process=time.monotonic(),
+                   bench=bench, overrides={"traffic": mix})
+    assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
+    assert set(out["metrics"]) == {"itl_p95_ms", "ttft_p90_ms", "setup_s"}
+    assert out["metrics"]["ttft_p90_ms"]["value"] > 0
+    calls = bench.reference(SSM).CALLS
+    assert len(calls) == 1 and calls[0]["requests"] == 4
+    _unedited(tmp_path, "harness", "references", "traffic", "metrics")
 
 
 def _run(cwd, env_extra=None):

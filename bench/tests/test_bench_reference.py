@@ -1,4 +1,4 @@
-"""The engine's prefill-then-decode logits against the plain reference.
+"""The engine's prefill-then-decode logits against the plain dense reference.
 
 At a small size on the CPU: requests of different prompt lengths are
 prefilled one at a time (B=1), spliced into the engine's batch cache and
@@ -7,17 +7,21 @@ blocks erased at every step.  Then one more decode step's logits, taken
 from the engine's cache, are compared with the reference's full forward
 over each prompt and its served tokens.
 """
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-import reference
+from harness import spec
+from harness.traffic import seed_key
 
-SMALL = dict(name="small", family="dense", n_layers=2, d_model=64, n_heads=4,
-             n_kv_heads=2, head_dim=16, d_ff=128, vocab=512, rope_theta=10000.0,
-             norm_eps=1e-5, coded=True, coded_parity=2)
+SMALL = dict(name="small", family="dense", reference="dense", n_layers=2,
+             d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab=512,
+             rope_theta=10000.0, norm_eps=1e-5, coded=True, coded_parity=2)
+reference = spec.load().reference(SMALL)
 MASK = np.ones(16, np.float32)
 MASK[[3, 11]] = 0.0
 # With float32 weights and activations the program still keeps cached keys
@@ -36,8 +40,8 @@ def _engine_logits(dtype: str):
     from repro.serve import Request, ServeEngine
 
     cfg = {**SMALL, "param_dtype": dtype, "dtype": dtype}
-    model = build_model(ModelConfig(**cfg))
-    params = jax.jit(model.init)(reference.seed_key(SEED))
+    model = build_model(ModelConfig(**{k: v for k, v in cfg.items() if k != "reference"}))
+    params = jax.jit(model.init)(seed_key(SEED))
     eng = ServeEngine(model, params, n_slots=3, s_max=64, mask_fn=lambda: MASK)
     g = np.random.default_rng(0)
     for uid, (n, new) in enumerate([(5, 6), (12, 5), (20, 8)]):
@@ -76,9 +80,10 @@ def test_reference_draws_the_programs_weights(dtype):
     from repro.models.registry import build_model
 
     cfg = {**SMALL, "param_dtype": dtype}
-    params = jax.jit(build_model(ModelConfig(**cfg)).init)(reference.seed_key(SEED))
+    mcfg = ModelConfig(**{k: v for k, v in cfg.items() if k != "reference"})
+    params = jax.jit(build_model(mcfg).init)(seed_key(SEED))
     dm = reference.Dims.of(cfg)
-    key = reference.seed_key(SEED)
+    key = seed_key(SEED)
     _, k_blocks, k_head = reference._top_keys(key)
     names = {"wq": ("attn_0", "w_q"), "wk": ("attn_0", "w_k"), "wv": ("attn_0", "w_v"),
              "wo": ("attn_0", "w_o"), "wg": ("mlp_0", "w_gate"), "wu": ("mlp_0", "w_up"),
@@ -90,3 +95,21 @@ def test_reference_draws_the_programs_weights(dtype):
                 np.asarray(w[mine]), np.asarray(params["blocks"][grp][leaf][i], np.float32))
     head = reference._dense(k_head, (dm.d_model, dm.vocab), jnp.dtype(dtype), dm.d_model)
     np.testing.assert_array_equal(np.asarray(head), np.asarray(params["lm_head"], np.float32))
+
+
+def test_dense_reference_is_unchanged_by_its_move():
+    """``references/dense.py`` gives, to the bit, the gaps, the control's
+    gaps and the last logits that ``bench/reference.py`` gave before it
+    moved (``data/dense_reference_gaps.npz``, written by that module on
+    the CPU for this configuration, seed and these requests)."""
+    cfg = {**SMALL, "n_kv_heads": 2, "param_dtype": "bfloat16", "dtype": "bfloat16"}
+    g = np.random.default_rng(1234)
+    prompts = [g.integers(0, 512, n).astype(np.int32) for n in (5, 17, 9)]
+    served = [g.integers(0, 512, n).astype(np.int32) for n in (6, 3, 11)]
+    gaps, gaps_c = reference.logit_gaps(cfg, SEED, prompts, served, 32, control=True)
+    last = reference.last_logits(
+        cfg, SEED, [np.concatenate([p, s]) for p, s in zip(prompts, served)], 32)
+    want = np.load(Path(__file__).parent / "data" / "dense_reference_gaps.npz")
+    np.testing.assert_array_equal(np.concatenate(gaps), want["gaps"])
+    np.testing.assert_array_equal(np.concatenate(gaps_c), want["gaps_c"])
+    np.testing.assert_array_equal(last, want["last"])

@@ -20,8 +20,13 @@ Timeline of a run (seconds on the bench's clock):
 * the check: the program's state is freed and the reference scores a
   sample of the finished requests (``bench/harness/check.py``).
 
-With ``trace`` the last ``TRACE_S`` seconds of the window are traced, and
-the per-layer metrics are read from that trace and the run's records.
+With ``trace`` the last ``TRACE_S`` seconds of the window are traced; the
+engine's public counters are read at the window's start and end, and once
+the window has closed, the operations of its compiled steps are mapped to
+their ``op_name``s (``ServeEngine.op_scopes()``, outside set-up and the
+window).  The per-layer metrics are read from the trace, those maps and
+counters and the run's records.  A run without ``trace`` reads none of
+them.
 """
 from __future__ import annotations
 
@@ -43,7 +48,6 @@ from .compilelog import CompileLog
 __all__ = ["run", "Run", "TRACE_S"]
 
 TRACE_S = 3.0           # traced seconds at the end of the window
-PROBE_CALLS = 32        # bench-driven calls of a kernel in a traced probe
 SPIN_S = 1e-3           # the last stretch of a wait for an arrival reads the clock
 SLOW_X = 4.0            # a window step this many times the median is a stall
 
@@ -93,7 +97,10 @@ class Run:
     device_kind: str
     traced: tuple[float, float] | None = None
     trace: tr.Trace | None = None
-    probes: dict = field(default_factory=dict)
+    # program -> instruction -> op_name, of the compiled steps (traced runs)
+    op_scopes: dict[str, dict[str, str]] = field(default_factory=dict)
+    # engine counter -> (window start, window end) (traced runs)
+    counters: dict[str, tuple[int, int]] = field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -144,6 +151,20 @@ def settle() -> None:
     it once the window is over."""
     gc.collect()
     gc.freeze()
+
+
+def engine_counters(eng) -> dict[str, int]:
+    """The engine's public ``int`` attributes: its counters, and sizes
+    such as ``n_slots``, whatever the family's engine keeps."""
+    return {k: v for k, v in vars(eng).items()
+            if not k.startswith("_") and type(v) is int}
+
+
+def _mark(marks: dict, name: str, log, eng, tracer) -> None:
+    """A mark of the CompileLog and, in a traced run, of the counters."""
+    marks[name] = log.mark()
+    if tracer:
+        marks[f"counters.{name}"] = engine_counters(eng)
 
 
 def sleep_until(clock, t: float) -> None:
@@ -278,9 +299,9 @@ def _drive_open(gen, model, params, cfg, mix, seed, seconds, mask_fn, tracer_at,
     while True:
         now = clock()
         if "window" not in marks and now >= t0:
-            marks["window"] = log.mark()
+            _mark(marks, "window", log, eng, tracer)
         if "window_end" not in marks and now >= t1:
-            marks["window_end"] = log.mark()
+            _mark(marks, "window_end", log, eng, tracer)
         if tracer:
             tracer.poll(now)
         if now >= t_end or (now >= t1 and all(sched.requests[i].done for i in in_win)):
@@ -292,7 +313,8 @@ def _drive_open(gen, model, params, cfg, mix, seed, seconds, mask_fn, tracer_at,
                 break
             with jax.profiler.TraceAnnotation("bench.wait"):
                 sleep_until(clock, min(nxt, t_end))
-    marks.setdefault("window_end", log.mark())
+    if "window_end" not in marks:
+        _mark(marks, "window_end", log, eng, tracer)
     for r in recs:
         sr = sched.requests[r.uid]
         r.tokens = list(payloads[r.uid].out_tokens)
@@ -342,14 +364,14 @@ def _drive_closed(gen, model, params, cfg, mix, seed, seconds, mask_fn,
     while True:
         now = clock()
         if "window" not in marks and now >= t0:
-            marks["window"] = log.mark()
+            _mark(marks, "window", log, eng, tracer)
         if tracer:
             tracer.poll(now)
         if now >= t1:
             break
         refill()
         led.step(clock)
-    marks["window_end"] = log.mark()
+    _mark(marks, "window_end", log, eng, tracer)
     by_uid = {r.uid: r for r in eng.completed}
     by_uid.update({r.uid: r for r in eng.slots if r is not None})
     for uid, rec in recs.items():
@@ -357,42 +379,6 @@ def _drive_closed(gen, model, params, cfg, mix, seed, seconds, mask_fn,
             rec.tokens = list(by_uid[uid].out_tokens)
             rec.times = led.stamps.get(uid, [])
     return eng, list(recs.values()), led.steps, (t0, t1), t0, tracer
-
-
-def _probe_coded_head(bench, params, cfg, mix, seed, n_blocks, log_dir):
-    """The program's coded-head matvec, called by the bench at the cell's
-    batch with the cell's erasure masks, traced on its own."""
-    import jax.numpy as jnp
-
-    from repro.kernels.ops import coded_head_matvec
-
-    n_par = cfg["coded_parity"]
-    n_data = n_blocks - n_par
-    b = cfg["engine"]["n_slots"]
-
-    def coded_head_probe(w, x, mask):
-        return coded_head_matvec(w, x, mask, n_data, n_par)
-
-    fn = jax.jit(coded_head_probe)
-    w = params["lm_head_coded"]
-    x = jax.random.normal(jax.random.key(seed % 2**32), (w.shape[1], b), jnp.float32)
-    src = traffic.erasure_fn(bench, mix, n_blocks, n_par, seed + 1)
-    masks = [jnp.asarray(src() if src else np.ones(n_blocks, np.float32))
-             for _ in range(PROBE_CALLS)]
-    jax.block_until_ready(fn(w, x, masks[0]))  # compiled in set-up
-    return lambda: _run_probe(fn, w, x, masks, log_dir)
-
-
-def _run_probe(fn, w, x, masks, log_dir):
-    start_trace(log_dir)
-    with jax.profiler.TraceAnnotation("bench.window"):
-        outs = [fn(w, x, m) for m in masks]
-        jax.block_until_ready(outs)
-    jax.profiler.stop_trace()
-    t = tr.load(tr.find_xplane(str(log_dir)))
-    shutil.rmtree(log_dir, ignore_errors=True)
-    return {"seconds": tr.module_seconds(t, "coded_head_probe"),
-            "w": jax.ShapeDtypeStruct(w.shape, w.dtype), "batch": x.shape[1]}
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -423,14 +409,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                if mcfg.coded else None)
     gen = bench.generator(mix["kind"])
 
-    per_layer = bench.metrics(workload, "per_layer") if trace else []
-    readers = {m["name"]: bench.reader(m["name"]) for m in per_layer}
-    want = {p for r in readers.values() for p in getattr(r, "PROBES", ())}
     trace_dir = bench.root / ".bench_trace"
-    probes = {}
-    if "coded_head" in want and mcfg.coded:
-        probes["coded_head"] = _probe_coded_head(bench, params, cfg, mix, seed,
-                                                 n_blocks, trace_dir / "probe")
 
     def tracer_at(t0, t1):
         if not trace:
@@ -444,11 +423,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if tracer and tracer.state == "on":
         tracer.poll(float("inf"))
     setup_s = setup_end - t_process
-    probe_out = {k: f() for k, f in probes.items()}
     stats = dev.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     params_sds, cache_sds = _sds(params), _sds(eng.cache)
-    del eng, params, probes
+    scopes, counters, scopes_s = {}, {}, None
+    if tracer:
+        t_scopes = time.monotonic()
+        scopes = eng.op_scopes()  # compiles again: a load from the cache
+        scopes_s = time.monotonic() - t_scopes
+        c0, c1 = marks["counters.window"], marks["counters.window_end"]
+        counters = {k: (c0[k], c1[k]) for k in c0 if k in c1}
+    del eng, params
     gc.unfreeze()
     gc.collect()
 
@@ -462,13 +447,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(trace_dir, ignore_errors=True)
     r = Run(cell, cfg, mix, seconds, setup_s, window, recs, steps, n_blocks,
             params_sds, cache_sds, dev.device_kind,
-            tracer.traced if tracer else None, t, probe_out)
+            tracer.traced if tracer else None, t, scopes, counters)
 
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in bench.metrics(workload, kind):
-        rd = readers.get(m["name"]) or bench.reader(m["name"])
-        v = rd.read(r)
+        v = bench.reader(m["name"]).read(r)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
@@ -490,6 +474,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     out = {"correct": bool(correct), "attempted": attempted, "failed": failed,
            "metrics": metrics, "device": device}
+    if tracer:
+        diag["op_scopes_s"] = scopes_s
     if t is not None:
         device["busy_s"] = tr.busy_s(t)
         device["window_s"] = t.window_s

@@ -26,6 +26,19 @@ module
 * draws its weights from ``harness.traffic.seed_key(seed)``, the one seed
   rule, and imports nothing from ``src/``.
 
+A family's layers are measured by reader files as well.  A reader of a
+layer inside the step reads the program's ``jax.named_scope``s through
+``readers.scope_ms(run, program, scope)``: the device time of the
+operations whose ``op_name`` holds the scope, from the trace and the
+compiled steps' maps (``Run.op_scopes``).  A reader of the host reads the
+program's spans (``engine.*``, kept in ``Run.trace.host`` beside the
+bench's ``bench.*``) through ``readers.idle_under_ms`` or the trace, and a
+reader of a count reads ``Run.counters``: every public ``int`` attribute
+of the engine at the window's start and end.  A family's operation and
+byte counts, which a kernel's share of its roofline needs, are a new
+module under ``bench/harness/`` named for the family, as ``cost.py`` holds
+the dense family's.  No harness file needs an edit.
+
 Adding a configuration, its reference, a mix, a generator or a metric is
 adding files and entries.
 """

@@ -1,11 +1,12 @@
 """Reduction of a ``jax.profiler`` trace to device busy time, program
-times and idle gaps attributed to the bench's host spans.
+times and idle gaps attributed to host spans.
 
 The trace is read with ``jax.profiler.ProfileData`` (the ``.xplane.pb``
 the profiler writes).  Devices are the planes ``/device:TPU:<n>``; on each,
 the line ``XLA Ops`` holds one event per operation run and ``XLA Modules``
-one per program run.  Host spans are the bench's own ``TraceAnnotation``
-events, named ``bench.*``, on the host plane; ``bench.window`` marks the
+one per program run.  Host spans are ``TraceAnnotation`` events on the
+host plane named with one of ``HOST_PREFIXES``: the bench's own
+(``bench.*``) and the program's (``engine.*``); ``bench.window`` marks the
 traced window.  Times are in nanoseconds.
 
 The device's clock runs early against the host's (by ~1.2 ms on a v5e):
@@ -13,7 +14,7 @@ a program shows as started before the host launched it.  Where the host's
 launches (``PJRT_LoadedExecutable_Execute``) and the device's program runs
 pair up one to one, the device's events are moved later by the least shift
 that puts no run before its launch; otherwise they are left as recorded
-and ``Trace.shift_ns`` is None.
+and ``Device.shift_ns`` is None.
 """
 from __future__ import annotations
 
@@ -23,10 +24,11 @@ import re
 from dataclasses import dataclass, field
 
 __all__ = ["Event", "Trace", "load", "find_xplane", "union_ns", "busy_s",
-           "module_seconds", "idle_gaps", "gaps_by_host", "top_ops"]
+           "module_seconds", "scope_seconds", "idle_gaps", "gaps_by_host",
+           "top_ops"]
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-HOST_PREFIX = "bench."
+HOST_PREFIXES = ("bench.", "engine.")
 WINDOW_SPAN = "bench.window"
 
 
@@ -100,7 +102,7 @@ def load(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 evs = _events(line)
-                host += [e for e in evs if e.name.startswith(HOST_PREFIX)]
+                host += [e for e in evs if e.name.startswith(HOST_PREFIXES)]
                 launches += [e.start for e in evs if e.name == LAUNCH]
     host.sort(key=lambda e: e.start)
     if len(devices) == 1:
@@ -196,29 +198,53 @@ def gaps_by_host(trace: Trace, device: int = 0) -> list[tuple[str, float]]:
     return sorted(out.items(), key=lambda kv: -kv[1])
 
 
-def top_ops(trace: Trace, n: int = 10, device: int = 0) -> list[tuple[str, float]]:
-    """Device seconds per operation within the window, named
-    ``<program>/<op>`` by the program run that encloses it, largest first.
-    An operation whose span holds the next one (a ``while`` around its
-    body's operations) is a container and is left out: its time is its
-    body's."""
+def _attributed(trace: Trace, device: int):
+    """Each operation of the window that is not a container, with the
+    program run that encloses it (None where none does).  An operation
+    whose span holds the next one (a ``while`` around its body's
+    operations) is a container: its time is its body's."""
     import bisect
 
-    if not trace.devices:
-        return []
     t0, t1 = trace.window
     d = trace.devices[device]
     mods = sorted(d.modules, key=lambda e: e.start)
     starts = [m.start for m in mods]
     ops = sorted(d.ops, key=lambda e: (e.start, -e.end))
-    tot: dict[str, float] = {}
     for i, e in enumerate(ops):
         if e.end <= t0 or e.start >= t1:
             continue
         if i + 1 < len(ops) and ops[i + 1].start < e.end and ops[i + 1].end <= e.end:
             continue
         j = bisect.bisect_right(starts, e.start) - 1
-        prog = mods[j].name if j >= 0 and mods[j].end >= e.start else "?"
+        yield (mods[j] if j >= 0 and mods[j].end >= e.start else None), e
+
+
+def top_ops(trace: Trace, n: int = 10, device: int = 0) -> list[tuple[str, float]]:
+    """Device seconds per operation within the window, named
+    ``<program>/<op>`` by the program run that encloses it, largest first;
+    containers are left out (``_attributed``)."""
+    if not trace.devices:
+        return []
+    t0, t1 = trace.window
+    tot: dict[str, float] = {}
+    for run, e in _attributed(trace, device):
+        prog = run.name if run is not None else "?"
         key = f"{prog.split('(')[0]}/{e.name}"
         tot[key] = tot.get(key, 0.0) + (min(e.end, t1) - max(e.start, t0)) * 1e-9
     return sorted(tot.items(), key=lambda kv: -kv[1])[:n]
+
+
+def scope_seconds(trace: Trace, part: str, names: set[str],
+                  device: int = 0) -> list[float]:
+    """Device seconds of each run of the programs whose name holds
+    ``part``, wholly within the window, spent in the operations named in
+    ``names``; containers are left out (``_attributed``)."""
+    if not trace.devices:
+        return []
+    t0, t1 = trace.window
+    runs = {id(m): 0.0 for m in trace.devices[device].modules
+            if part in m.name and m.start >= t0 and m.end <= t1}
+    for run, e in _attributed(trace, device):
+        if run is not None and id(run) in runs and e.name in names:
+            runs[id(run)] += (e.end - e.start) * 1e-9
+    return list(runs.values())

@@ -1,19 +1,18 @@
-"""Share of its roofline that the coded head reaches: the program's
-``coded_head_matvec`` called by the bench at the cell's batch with the
-cell's erasure masks, traced on its own; least time (the ``n_data`` blocks
-any decode needs, ``harness/cost.py``) over device time per call."""
-import numpy as np
-
+"""Share of its roofline that the coded head reaches inside the decode
+step: its least time at the engine's batch (the ``n_data`` blocks any
+decode needs, ``harness/cost.py``) over the device time per run of
+``_decode_argmax`` of the operations under the model's ``coded_head``
+scope."""
 from harness import cost
-
-PROBES = ("coded_head",)
+from harness.readers import scope_ms
 
 
 def read(run):
-    p = run.probes.get("coded_head")
-    if not p or not p["seconds"]:
+    ms = scope_ms(run, "_decode_argmax", "coded_head")
+    if ms is None:
         return None
-    shp = cost.DenseShape.of(run.cfg, run.n_blocks)
-    flops, nbytes = cost.coded_head_cost(p["w"], p["batch"], shp.n_data, shp.n_parity)
-    least = cost.least_seconds(flops, nbytes, run.peaks)
-    return 100.0 * least / float(np.mean(p["seconds"]))
+    n_parity = run.cfg["coded_parity"]
+    flops, nbytes = cost.coded_head_cost(
+        run.params["lm_head_coded"], run.cfg["engine"]["n_slots"],
+        run.n_blocks - n_parity, n_parity)
+    return 100.0 * cost.least_seconds(flops, nbytes, run.peaks) / (ms * 1e-3)

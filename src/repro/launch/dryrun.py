@@ -175,6 +175,12 @@ def build_cell(cfg: ModelConfig, wl: Workload, mesh, *, coded: bool = False,
     if wl.kind == "decode":
         cache_sds = model.cache_shapes(wl.batch, wl.seq)
         cache_sh = jax.tree.map(ns, policy.cache_specs(cache_sds))
+        # the model picks its KV-row write from this sharding
+        # (sharding.ctx.kv_seq_sharded)
+        kv_sh = [sh for path, sh in jax.tree_util.tree_leaves_with_path(cache_sh)
+                 if getattr(path[-1], "key", None) == "k"]
+        if kv_sh:
+            hints = {**hints, "kv_cache": kv_sh[0]}
         tok_sds = SDS((wl.batch,), jnp.int32)
         tok_sh = ns(P(policy.dp_axes if not small_batch else None))
         fn = jax.jit(

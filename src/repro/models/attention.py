@@ -1,8 +1,10 @@
 """Grouped-query attention: full (train/prefill), decode (KV cache), cross.
 
 Layout convention: activations [B, S, D]; per-head tensors [B, S, H, Hd];
-KV caches [B, S_max, KVH, Hd].  Softmax in fp32.  TP shards the head axis
-(uneven head counts are allowed — GSPMD pads; see DESIGN.md §5).
+KV caches [B, S_max, KVH, Hd].  Decode reads the cache and returns the new
+rows; ``write_kv_rows`` stores them for every layer at once.  Softmax in
+fp32.  TP shards the head axis (uneven head counts are allowed — GSPMD
+pads; see DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -10,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import Params, apply_rope, dense_init
+from repro.sharding.ctx import kv_seq_sharded
 
 NEG_INF = -1e30
 
@@ -141,47 +144,95 @@ def attention_full(
     return jnp.einsum("bshk,hkd->bsd", out, p["w_o"].astype(dt))
 
 
+def _sdpa_decode(q, cache_k, cache_v, k, v, pos) -> jnp.ndarray:
+    """One query per sequence over its cached keys ``< pos`` and its own new
+    key/value, in one softmax: the same as a mask ``<= pos`` over a cache
+    that already holds the new row, without writing it first.
+
+    q [B,1,H,Hd], cache_k/v [B,S_max,KVH,Hd], k/v [B,KVH,Hd], pos [B]."""
+    b, _, h, hd = q.shape
+    kvh = cache_k.shape[2]
+    q = q.reshape(b, 1, kvh, h // kvh, hd)
+    scale = jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    logits = jnp.einsum("bqgmd,bkgd->bgmqk", q, cache_k).astype(jnp.float32) / scale
+    own = jnp.einsum("bqgmd,bgd->bgmq", q, k).astype(jnp.float32)[..., None] / scale
+    cached = (jnp.arange(cache_k.shape[1])[None, :] < pos[:, None])[:, None, None, None, :]
+    logits = jnp.where(cached, logits, NEG_INF)
+    top = jnp.maximum(logits.max(axis=-1, keepdims=True), own)
+    e, e_own = jnp.exp(logits - top), jnp.exp(own - top)
+    denom = e.sum(axis=-1, keepdims=True) + e_own
+    probs, p_own = (e / denom).astype(v.dtype), (e_own / denom).astype(v.dtype)
+    out = jnp.einsum("bgmqk,bkgd->bqgmd", probs, cache_v,
+                     preferred_element_type=jnp.float32)
+    out = out + jnp.einsum("bgmqk,bgd->bqgmd", p_own, v,
+                           preferred_element_type=jnp.float32)
+    return out.astype(v.dtype).reshape(b, 1, h, hd)
+
+
 def attention_decode(
     p: Params,
     x: jnp.ndarray,            # [B, 1, D] — one new token per sequence
     cache_k: jnp.ndarray,      # [B, S_max, KVH, Hd]
     cache_v: jnp.ndarray,
-    pos: jnp.ndarray,          # [B] int — write/attend position per sequence
+    pos: jnp.ndarray,          # [B] int — the new token's position per sequence
     theta: float,
     n_real: int | None = None,
-    aligned: bool = False,     # all sequences share one position (batch-
-    #   aligned decoding): O(1)-token dynamic_update_slice instead of the
-    #   masked full-cache rewrite (§Perf: halves decode cache traffic)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One decode step; returns (out [B,1,D], new_k, new_v)."""
+    """One decode step; returns (out [B,1,D], k, v): the new token's key and
+    value rows [B, KVH, Hd] in the cache dtype, for ``write_kv_rows``.  The
+    cache is only read."""
     dt = x.dtype
     q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["w_k"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, p["w_v"].astype(dt))
     q = apply_rope(q, pos[:, None], theta)
     k = apply_rope(k, pos[:, None], theta)
-
-    s_max = cache_k.shape[1]
-    with jax.named_scope("kv_write"):  # named in the compiled op_names
-        if aligned:
-            cache_k = jax.lax.dynamic_update_slice_in_dim(
-                cache_k, k.astype(cache_k.dtype), pos[0], axis=1)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(
-                cache_v, v.astype(cache_v.dtype), pos[0], axis=1)
-        else:
-            # masked one-hot write (NOT vmapped dynamic_update_slice): per-seq
-            # scatter positions make the SPMD partitioner fall into
-            # pathological resharding when the cache's sequence dim is
-            # sharded — the elementwise select shards trivially at the cost
-            # of rewriting the cache (decode already reads it; ~1.5x
-            # traffic, charged honestly)
-            hot = (jnp.arange(s_max)[None, :] == pos[:, None])[..., None, None]
-            cache_k = jnp.where(hot, k[:, 0][:, None].astype(cache_k.dtype), cache_k)
-            cache_v = jnp.where(hot, v[:, 0][:, None].astype(cache_v.dtype), cache_v)
-    mask = (jnp.arange(s_max)[None, :] <= pos[:, None])[:, None, None, None, :]
-    out = _sdpa(q, cache_k.astype(dt), cache_v.astype(dt), mask)
+    # the new rows as the cache will hold them, so this step attends to
+    # exactly what later steps read back
+    k, v = k[:, 0].astype(cache_k.dtype), v[:, 0].astype(cache_v.dtype)
+    out = _sdpa_decode(q, cache_k.astype(dt), cache_v.astype(dt),
+                       k.astype(dt), v.astype(dt), pos)
     out = _mask_pad_heads(out, n_real)
-    return jnp.einsum("bshk,hkd->bsd", out, p["w_o"].astype(dt)), cache_k, cache_v
+    return jnp.einsum("bshk,hkd->bsd", out, p["w_o"].astype(dt)), k, v
+
+
+def write_kv_rows(
+    cache: jnp.ndarray,        # [*G, B, S_max, KVH, Hd]
+    rows: jnp.ndarray,         # [*G, B, KVH, Hd]
+    pos: jnp.ndarray,          # [B]
+    aligned: bool = False,     # every sequence at pos[0] (batch-aligned decode)
+) -> jnp.ndarray:
+    """Store each sequence's new key or value row at its ``pos``, for every
+    stacked layer ``G`` at once.
+
+    One ``dynamic_update_slice`` per sequence (one for the whole batch when
+    ``aligned``) writes ``[*G, KVH, Hd]`` in whatever layout the compiler
+    gave the cache; in a step that donates its cache they run in place, so
+    the step moves the new rows and never the cache.  (A scatter of all
+    rows at once is one operation, but the TPU compiler runs it only in the
+    major-to-minor layout, and relays out a cache it stores sequence-minor,
+    as it does phi3-mini's 96-wide heads, twice a step.)
+
+    The exception is a cache whose sequence axis is sharded
+    (``sharding.ctx.kv_seq_sharded``: the dry run's caches whose KV heads
+    do not divide the model axis).  Per-sequence write positions make the
+    SPMD partitioner fall into pathological resharding there, so the rows
+    go in by a masked select over the whole cache, which shards
+    elementwise at the cost of rewriting it.
+
+    Callers write every leaf and advance ``pos`` inside one
+    ``jax.named_scope("kv_write")``: the step's appends to its cache."""
+    rows = rows.astype(cache.dtype)[..., None, :, :]  # [*G, B, 1, KVH, Hd]
+    if kv_seq_sharded():
+        hot = jnp.arange(cache.shape[-3])[None, :] == pos[:, None]
+        return jnp.where(hot[..., None, None], rows, cache)
+    lead = (0,) * (rows.ndim - 4)
+    if aligned:
+        return jax.lax.dynamic_update_slice(cache, rows, lead + (0, pos[0], 0, 0))
+    for b in range(rows.shape[-4]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[..., b:b + 1, :, :, :], lead + (b, pos[b], 0, 0))
+    return cache
 
 
 # --------------------------------------------------------------------------

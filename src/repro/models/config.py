@@ -70,8 +70,8 @@ class ModelConfig:
     #   masked so the function (and grads) equal the unpadded model exactly
     moe_dispatch_groups: int = 1  # shard-local MoE capacity/cumsum groups
     #   (breaks the cross-shard sequential cumsum chain)
-    aligned_decode: bool = False  # batch-aligned decode positions: O(1)-token
-    #   cache write (vs masked full-cache rewrite for ragged positions)
+    aligned_decode: bool = False  # batch-aligned decode positions: one
+    #   update-slice stores every slot's new KV row (vs one per slot)
 
     def __post_init__(self):
         if self.family not in ("dense", "moe", "ssm", "hybrid", "vlm", "encdec"):

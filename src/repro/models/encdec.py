@@ -19,6 +19,7 @@ from repro.models.attention import (
     init_attn,
     init_cross_attn,
     precompute_cross_kv,
+    write_kv_rows,
 )
 from repro.models.config import ModelConfig
 from repro.models.layers import Params, dense_init, embed_init, init_mlp, mlp_apply, rmsnorm
@@ -200,9 +201,11 @@ def encdec_decode_step(
         h2 = rmsnorm(x, lp["ln2"], cfg.norm_eps)
         return x + mlp_apply(lp["mlp"], h2, cfg.mlp), {"k": nk, "v": nv}
 
-    x, kvs = jax.lax.scan(
+    x, rows = jax.lax.scan(
         body, x, (params["dec_blocks"], cache["k"], cache["v"], cache["ck"], cache["cv"])
     )
-    new_cache = {"pos": pos + 1, "ck": cache["ck"], "cv": cache["cv"], **kvs}
+    with jax.named_scope("kv_write"):  # named in the compiled op_names
+        new_cache = {"pos": pos + 1, "ck": cache["ck"], "cv": cache["cv"],
+                     **{t: write_kv_rows(cache[t], rows[t], pos) for t in ("k", "v")}}
     hidden = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return _last_logits(params, hidden), new_cache

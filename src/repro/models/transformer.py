@@ -14,8 +14,9 @@ cleanly with bounded HLO:
 Group params are stacked on a leading group axis and consumed by
 ``lax.scan`` with optional per-group ``jax.checkpoint`` (remat).  KV /
 recurrent caches mirror the same stacking, so decode scans (params, cache)
-jointly.  The CE loss is computed in sequence chunks so [B, S, V] fp32
-logits are never resident.
+jointly; the scan reads the KV cache and emits each layer's new rows, and
+one write per cache leaf stores them after it.  The CE loss is computed in
+sequence chunks so [B, S, V] fp32 logits are never resident.
 """
 from __future__ import annotations
 
@@ -33,6 +34,7 @@ from repro.models.attention import (
     init_attn,
     init_cross_attn,
     precompute_cross_kv,
+    write_kv_rows,
 )
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -538,7 +540,7 @@ def lm_decode_step(
 
     if cfg.family == "hybrid":
         new_blocks = []
-        app = 0
+        rows_k, rows_v = [], []
         for i, gp in enumerate(params["blocks"]):
             st = cache["blocks"][i]["mamba"]
             h, st2 = mamba_block_apply(
@@ -547,11 +549,19 @@ def lm_decode_step(
             x = x + h
             new_blocks.append({"mamba": st2})
             if (i + 1) % cfg.attn_every == 0:
-                x, cache = _shared_attn_decode(params, cfg, cache, x, pos, app)
-                app += 1
-        new_cache = dict(cache)
-        new_cache["blocks"] = new_blocks
-        new_cache["pos"] = pos + 1
+                x, nk, nv = _shared_attn_decode(params, cfg, cache, x, pos, len(rows_k))
+                rows_k.append(nk)
+                rows_v.append(nv)
+        shared = cache["shared_attn"]
+        with jax.named_scope("kv_write"):  # named in the compiled op_names
+            new_cache = {
+                "pos": pos + 1,
+                "blocks": new_blocks,
+                "shared_attn": {
+                    "k": write_kv_rows(shared["k"], jnp.stack(rows_k), pos),
+                    "v": write_kv_rows(shared["v"], jnp.stack(rows_v), pos),
+                },
+            }
         hidden = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         return _last_logits(params, hidden, cfg, head_mask), new_cache
 
@@ -571,18 +581,19 @@ def lm_decode_step(
 
     r = group_period(cfg)
 
+    # the scan only reads the cache; its outputs are each layer's new rows,
+    # which one write per leaf stores after it (in place when donated)
     def body(x, inp):
         gp, cg = inp
-        new_cg = dict(cg)
+        rows = {}
         for j in range(r):
             h = rmsnorm(x, gp[f"ln1_{j}"], cfg.norm_eps)
             out, nk, nv = attention_decode(
                 gp[f"attn_{j}"], h, cg[f"attn_{j}"]["k"], cg[f"attn_{j}"]["v"], pos,
                 cfg.rope_theta,
                 n_real=cfg.n_heads if cfg.pad_heads else None,
-                aligned=cfg.aligned_decode,
             )
-            new_cg[f"attn_{j}"] = {"k": nk, "v": nv}
+            rows[f"attn_{j}"] = {"k": nk, "v": nv}
             x = x + out
             if cfg.family == "vlm" and j == r - 1:
                 hx = rmsnorm(x, gp[f"lnx_{j}"], cfg.norm_eps)
@@ -599,27 +610,30 @@ def lm_decode_step(
             else:
                 y = mlp_apply(gp[f"mlp_{j}"], h2, cfg.mlp)
             x = x + y
-        return x, new_cg
+        return x, rows
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
-    new_cache = {"pos": pos + 1, "blocks": new_blocks}
+    x, rows = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    blocks = dict(cache["blocks"])
+    with jax.named_scope("kv_write"):  # named in the compiled op_names
+        for name, kv in rows.items():
+            blocks[name] = {
+                t: write_kv_rows(cache["blocks"][name][t], kv[t], pos, cfg.aligned_decode)
+                for t in ("k", "v")
+            }
+        new_cache = {"pos": pos + 1, "blocks": blocks}
     hidden = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return _last_logits(params, hidden, cfg, head_mask), new_cache
 
 
 def _shared_attn_decode(params, cfg, cache, x, pos, app: int):
-    """Apply the zamba2 shared attention block at decode with its own cache
-    slice (weights shared; caches per application)."""
+    """Apply the zamba2 shared attention block at decode against its own
+    cache slice (weights shared; caches per application).  Returns
+    ``(x, k, v)``: the application's new key/value rows."""
     sp = params["shared_attn"]
     h = rmsnorm(x, sp["ln1"], cfg.norm_eps)
     ck = cache["shared_attn"]["k"][app]
     cv = cache["shared_attn"]["v"][app]
     out, nk, nv = attention_decode(sp["attn"], h, ck, cv, pos, cfg.rope_theta)
-    new_cache = dict(cache)
-    new_cache["shared_attn"] = {
-        "k": cache["shared_attn"]["k"].at[app].set(nk),
-        "v": cache["shared_attn"]["v"].at[app].set(nv),
-    }
     x = x + out
     h2 = rmsnorm(x, sp["ln2"], cfg.norm_eps)
-    return x + mlp_apply(sp["mlp"], h2, cfg.mlp), new_cache
+    return x + mlp_apply(sp["mlp"], h2, cfg.mlp), nk, nv

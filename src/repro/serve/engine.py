@@ -33,7 +33,9 @@ next step without a round-trip, and exactly ONE device->host transfer per
 step (the [n_slots] int32 token vector) serves the bookkeeping (EOS, output
 accumulation).  The seed engine pulled the full [n_slots, vocab] fp32
 logits to host and argmax'd in numpy — at 100k+ vocab that transfer was
-the per-token critical path.
+the per-token critical path.  The decode programs donate the KV cache:
+a step stores each slot's new key/value rows in place in the buffers it
+was handed, so no step copies the cache.
 
 Fused macro-steps (DESIGN.md §14): with ``macro_steps=K_max > 1`` the
 engine can decode K steps per launch — a jitted ``lax.scan`` keeps
@@ -289,7 +291,8 @@ class ServeEngine:
                 logits, cache1 = model.prefill(params, batch, s_max=s_max)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache1
 
-        self._decode = jax.jit(_decode_argmax)
+        # the cache is donated: the step stores its new rows in place
+        self._decode = jax.jit(_decode_argmax, donate_argnums=(1,))
         self._prefill1 = jax.jit(_prefill_argmax)
         # fused-block jit bucket cache, keyed by K.  Shape (n_slots, s_max)
         # and parity geometry are fixed per bind — a parity raise re-binds
@@ -782,7 +785,7 @@ class ServeEngine:
             (lt, cache), toks = jax.lax.scan(body, (last_tok, cache), masks)
             return toks, lt, cache
 
-        fn = jax.jit(_decode_block)
+        fn = jax.jit(_decode_block, donate_argnums=(1,))
         self._decode_block[k] = fn
         return fn
 

@@ -108,6 +108,16 @@ def macro_step_k(k: int | None):
         _MACRO_K.reset(token)
 
 
+def kv_seq_sharded() -> bool:
+    """Is the decode KV cache's sequence axis sharded?  A launcher that
+    shards the cache states its sharding as the ``kv_cache`` hint (one
+    full-rank ``[..., B, S, KVH, Hd]`` spec); without it (the serving
+    engine, unit tests) the cache is whole on each device."""
+    hints = _HINTS.get()
+    sharding = hints.get("kv_cache") if hints else None
+    return sharding is not None and sharding.spec[-3] is not None
+
+
 def shard_hint(x: jax.Array, name: str) -> jax.Array:
     """Constrain ``x`` to the installed spec for ``name`` (no-op if unset)."""
     hints = _HINTS.get()

@@ -211,3 +211,77 @@ def test_decode_step_reads_coded_head_in_place(one_chip, name):
     assert not copies, f"the step rewrites the coded head: {copies}"
     temp, head_bytes = compiled.memory_analysis().temp_size_in_bytes, head_elems * 4
     assert temp < head_bytes / 4, (temp, head_bytes)
+
+
+# an instruction's name, result dims, opcode and operands:
+# `%name = bf16[a,b]{layout} op(%x, %y), ...`
+_INSTR_ARGS = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([0-9,]*)\]\S*\s+"
+                         r"([\w\-]+)\(([^)]*)\)")
+# the KV writes: the model's kv_write scope, or a layer scan's stacking of
+# its per-layer outputs
+_WRITE_SIDE = re.compile(r'op_name="[^"]*(?:/kv_write/|while/body/dynamic_update_slice)')
+_ALIASED = re.compile(r"\{[0-9, ]*\}:\s*\((\d+),")
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no-mask", "mask"])
+@pytest.mark.parametrize("name", list(DECODE_CELLS))
+def test_decode_step_writes_kv_cache_in_place(one_chip, name, masked):
+    """The engine's decode step with its cache donated stores each slot's
+    new key/value row and moves no whole cache to do it: the module
+    aliases every cache leaf from input to output; no select, copy or
+    dynamic-update-slice on the write side (the ``kv_write`` scope or the
+    layer scan's stacking of its outputs), in the step or in a fusion
+    body, writes as many elements as one layer's or the whole stack's K/V
+    cache (a dynamic-update-slice counts its update, since in place it
+    moves only that); and the step's temporaries stay far below one
+    stacked cache, so no hidden relayout of it is left."""
+    from repro.models.config import ModelConfig
+    from repro.models.registry import build_model
+
+    widths, n_slots, s_max = DECODE_CELLS[name]
+    model = build_model(ModelConfig(name=name, **widths, **_BODY))
+
+    def place(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = place(model.param_shapes())
+    cache = place(model.cache_shapes(n_slots, s_max))
+    mask = _sds((N_BLOCKS,), jnp.float32, one_chip) if masked else None
+
+    def decode_argmax(params, cache, last_tok, mask):
+        logits, cache = model.decode_step(params, cache, last_tok, mask)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    compiled = jax.jit(decode_argmax, donate_argnums=(1,), keep_unused=True).lower(
+        params, cache, _sds((n_slots,), jnp.int32, one_chip), mask).compile()
+    text = compiled.as_text()
+
+    first = len(jax.tree.leaves(params))
+    cache_params = set(range(first, first + len(jax.tree.leaves(cache))))
+    header = next(line for line in text.splitlines() if line.startswith("HloModule"))
+    aliased = {int(p) for p in _ALIASED.findall(header)}
+    assert cache_params <= aliased, (sorted(cache_params - aliased), header[:300])
+
+    stacked = cache["blocks"]["attn_0"]["k"]
+    layer = math.prod(stacked.shape[1:])
+    whole = {layer, layer * stacked.shape[0]}
+    dims: dict[str, int] = {}
+    writes = []
+    for line in text.splitlines():
+        m = _INSTR_ARGS.match(line)
+        if m is None:
+            continue
+        iname, shape, op, args = m.groups()
+        dims[iname] = math.prod(int(d) for d in shape.split(",") if d)
+        if op not in ("select", "copy", "dynamic-update-slice") or not _WRITE_SIDE.search(line):
+            continue
+        moved = dims[iname]
+        if op == "dynamic-update-slice":  # in place it moves its update only
+            update = args.split(",")[1].strip().lstrip("%")
+            moved = dims.get(update, moved)
+        if moved in whole:
+            writes.append(line.strip()[:200])
+    assert not writes, f"the step rewrites a whole K/V cache: {writes}"
+    cache_bytes = stacked.dtype.itemsize * math.prod(stacked.shape)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 8, (temp, cache_bytes)
